@@ -54,6 +54,10 @@ def _all_basenames() -> set:
 
 def _source_flags() -> set:
     flags = set(IMPLICIT_FLAGS)
+    for f in os.listdir(ROOT):         # root entry points: chip_smoke.py
+        if f.endswith(".py"):
+            with open(os.path.join(ROOT, f)) as fh:
+                flags.update(ADD_ARG_RE.findall(fh.read()))
     for sub in ("src", "benchmarks", "examples"):
         for dirpath, _dirs, files in os.walk(os.path.join(ROOT, sub)):
             for f in files:

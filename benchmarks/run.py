@@ -324,7 +324,8 @@ def bench_train_step(quick):
     from repro.train.step import (build_parallel, build_train_step,
                                   init_train_state)
     cfg = reduced_config(get_config("stablelm-3b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(1, 1)
     for kind, pipeline in (("none", "reference"), ("topk", "reference"),
                            ("regtopk", "reference"), ("regtopk", "fused")):
         run = RunConfig(model=cfg, shape=SHAPES["train_4k"],
@@ -332,7 +333,7 @@ def bench_train_step(quick):
                                                     pipeline=pipeline),
                         optimizer=OptimizerConfig(kind="adam", lr=1e-3))
         pal = build_parallel(mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             params, opt_state, ef_state = init_train_state(
                 run, mesh, pal, jax.random.PRNGKey(0))
             step, _, _ = build_train_step(run, mesh, pal)

@@ -33,7 +33,7 @@ def main():
     pal = build_parallel(mesh)
     key = jax.random.PRNGKey(0)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params, opt_state, ef_state = init_train_state(run, mesh, pal, key)
         step, _, _ = build_train_step(run, mesh, pal)
         jstep = jax.jit(step, donate_argnums=(0, 1, 2))
@@ -54,7 +54,7 @@ def main():
         run, shape=dataclasses.replace(SHAPES["decode_32k"], seq_len=96,
                                        global_batch=8))
     spal = serve_parallel(mesh, srun, decode=True)
-    with mesh:
+    with jax.set_mesh(mesh):
         pre, _ = build_prefill(srun, mesh, spal)
         dec, _ = build_decode_step(srun, mesh, spal)
         prompt = jax.random.randint(key, (8, 16), 0, cfg.vocab_size)

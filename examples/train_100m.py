@@ -57,7 +57,7 @@ def main():
     mesh = make_mesh(data=4, model=2)
     pal = build_parallel(mesh)
     key = jax.random.PRNGKey(0)
-    with mesh:
+    with jax.set_mesh(mesh):
         params, opt_state, ef_state = init_train_state(run, mesh, pal, key)
         n = sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
         print(f"{cfg.name}: {n/1e6:.1f}M params, REGTOP-k S={args.sparsity}, "

@@ -1,27 +1,2 @@
 """repro: production-scale JAX/Pallas reproduction of REGTOP-k
 (Novel Gradient Sparsification Algorithm via Bayesian Inference)."""
-import functools as _functools
-
-import jax as _jax
-
-# jax < 0.5 exposes shard_map only under jax.experimental (with the
-# replication check spelled check_rep rather than check_vma); the
-# codebase targets the stable jax.shard_map spelling.
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _compat_shard_map(f, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _compat_shard_map
-
-# jax < 0.5 has no jax.lax.axis_size; psum(1, axis) is the classic
-# spelling (constant-folded by XLA inside shard_map).
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size

@@ -83,14 +83,20 @@ def _hashes(j: int, rows: int, width: int):
 
 
 def encode(a: jnp.ndarray, rows: int, width: int) -> jnp.ndarray:
-    """a (J,) -> sketch (rows, width). Linear in a."""
-    h, s = _hashes(a.shape[0], rows, width)
+    """a (J,) -> sketch (rows, width). Linear in a.
+
+    One 1D scatter-add per row, hashing the index stream row by row, so
+    no (rows, J) hash/sign array is built: each row costs one J-sized
+    scatter pass over ``a``."""
+    idx = jnp.arange(a.shape[0], dtype=jnp.uint32)
     af = a.astype(jnp.float32)
-
-    def one_row(hr, sr):
-        return jnp.zeros((width,), jnp.float32).at[hr].add(sr * af)
-
-    return jax.vmap(one_row)(h, s)
+    out = []
+    for r in range(rows):
+        x = idx * _MULTS[r] + _ADDS[r]
+        h = jax.lax.rem(x >> 8, jnp.uint32(width)).astype(jnp.int32)
+        s = ((x >> 31) & 1).astype(jnp.float32) * 2.0 - 1.0
+        out.append(jnp.zeros((width,), jnp.float32).at[h].add(s * af))
+    return jnp.stack(out)
 
 
 def estimate(sketch: jnp.ndarray, j: int) -> jnp.ndarray:

@@ -22,14 +22,16 @@ performs:
   candidate space, never densely — ``idx_prev`` doubles as the support
   set for the candidate/support membership test.
 
-Execution strategies (auto-selected from the JAX backend by ``ops``):
+Execution strategies (``ops.resolve_strategy``):
 
-- ``pallas``:  native Pallas kernels (TPU). Threshold from the
-  accumulated bit-pattern histogram; compaction via per-block slots.
-- ``xla``:     batched-row ``lax.top_k`` compaction (CPU/GPU). Same
-  candidate contract, no interpret-mode overhead.
+- ``xla``:     batched-row ``lax.top_k`` compaction, the default on
+  every backend (TPU and CPU).
 - ``pallas_interpret``: the Pallas kernels under ``interpret=True`` —
-  used by tests to validate the kernel bodies on CPU.
+  threshold from the accumulated bit-pattern histogram, compaction via
+  per-block slots; used by tests to validate the kernel bodies.
+- ``pallas``:  the same kernels compiled natively. The TPU compiler
+  refuses them, so this raises ``ops.PALLAS_TPU_REFUSAL`` before
+  tracing.
 
 Both strategies verify exactness (per-block overflow + boundary-tie
 ambiguity) and fall back to a full ``lax.top_k`` under ``lax.cond`` on

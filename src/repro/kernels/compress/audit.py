@@ -60,6 +60,7 @@ from __future__ import annotations
 from collections import defaultdict
 
 import jax
+import jax.extend.core
 import numpy as np
 
 _ELEMENTWISE = {
@@ -150,7 +151,7 @@ def audit_jaxpr(jaxpr, j: int, unit_bytes: int = 4,
         nonlocal barrier_weight, read_bytes, write_bytes
         for eqn in eqns:
             prim = eqn.primitive.name
-            if prim in ("pjit", "closed_call", "custom_jvp_call",
+            if prim in ("jit", "pjit", "closed_call", "custom_jvp_call",
                         "custom_vjp_call", "custom_vjp_call_jaxpr",
                         "remat", "checkpoint"):
                 # the sub-jaxpr's vars are disjoint from the outer ones,
@@ -173,7 +174,7 @@ def audit_jaxpr(jaxpr, j: int, unit_bytes: int = 4,
                     # donated) operand stays donated inside the branch
                     don_br = {bv for bv, ov in zip(br.jaxpr.invars,
                                                    eqn.invars[1:])
-                              if not isinstance(ov, jax.core.Literal)
+                              if not isinstance(ov, jax.extend.core.Literal)
                               and alias_root.get(ov) in donated}
                     results.append(audit_jaxpr(br, j, unit_bytes,
                                                donated=frozenset(don_br)))

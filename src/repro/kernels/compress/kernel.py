@@ -30,8 +30,15 @@ outside the kernel (ops.py).
 Scalars (step flag, tau) travel as (1, 1) inputs; static config (mode,
 regularizer constant, bins) is baked into the kernel body.
 
-TPU-native (non-interpret) validation is an open ROADMAP item; tests
-exercise these kernels under ``interpret=True`` on CPU.
+These kernels run only under ``interpret=True`` (``ops`` strategy
+``pallas_interpret``). The v5e compiler (libtpu 0.0.34, JAX 0.9.0)
+refuses every one of them natively: their ``(1, BLOCK)``, ``(1, 1)``,
+``(1, bins)`` and ``(1, maxpb)`` blocks break the rule that the last two
+block dimensions divide by 8 and 128, and even with aligned blocks
+Pallas TPU lowering has no rule for the in-kernel scatter-add histogram,
+the ``cumsum`` compaction, the ``.at[].set`` slot scatters or the sketch
+scatter-add. ``ops.PALLAS_TPU_REFUSAL`` is the error ``strategy="pallas"``
+raises; the chip runs ``strategy="xla"``.
 """
 from __future__ import annotations
 
@@ -318,20 +325,6 @@ def _sketch_accum(a, base, sk_ref, *, rows: int, width: int, block: int,
         sk_ref[r, :] += jnp.zeros((width,), jnp.float32).at[h].add(s * a)
 
 
-def _sketch_encode_kernel(a_ref, sk_ref, *, rows: int, width: int,
-                          block: int, mults, adds):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        sk_ref[...] = jnp.zeros_like(sk_ref)
-
-    a = a_ref[...].astype(jnp.float32)[0]                      # (block,)
-    base = jax.lax.convert_element_type(i, jnp.uint32) * block
-    _sketch_accum(a, base, sk_ref, rows=rows, width=width, block=block,
-                  mults=mults, adds=adds)
-
-
 def _sweep1_sketch_kernel(g_ref, err_ref, a_ref, sk_ref, *, rows: int,
                           width: int, block: int, mults, adds):
     i = pl.program_id(0)
@@ -363,31 +356,6 @@ def _sketch_grid(j: int, interpret: bool = True):
         cap = max(cap, -(-j // (8 * 128)) * 128)
     block = min(cap, -(-j // 128) * 128)
     return block, -(-j // block) * block
-
-
-def sketch_encode_pallas(a, *, rows: int, width: int, mults, adds,
-                         interpret: bool = True):
-    """a (J,) -> CountSketch (rows, width), bit-identical to
-    core.sketch.encode at the same constants. ONE pallas barrier: the
-    per-block scatter-adds accumulate into the (rows, width) output
-    block, so no (rows, J) hash/sign intermediate is ever materialized
-    (the legacy encode's dominant traffic)."""
-    j = a.shape[0]
-    block, j_pad = _sketch_grid(j, interpret)
-    if j_pad != j:
-        a = jnp.pad(a.astype(jnp.float32), (0, j_pad - j))
-    grid = j_pad // block
-    sk = pl.pallas_call(
-        functools.partial(_sketch_encode_kernel, rows=rows, width=width,
-                          block=block, mults=tuple(mults),
-                          adds=tuple(adds)),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, width), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
-        interpret=interpret,
-    )(a.astype(jnp.float32).reshape(grid, block))
-    return sk
 
 
 def sweep1_sketch_pallas(g, err_prev, *, rows: int, width: int, mults,

@@ -48,10 +48,11 @@ makes streaming bit-identical — and S partial sweeps of J/S elements
 still audit as the same 2 traversals (the streaming reorders WHEN
 sweeps run, not how many).
 
-The execution strategy is auto-selected from the JAX backend (the
-"interpret or not" decision the old kernels hardcoded): native Pallas
-kernels on TPU, fusion-friendly XLA lowering elsewhere, and
-``pallas_interpret`` for validating the kernel bodies in tests.
+The execution strategy is ``xla`` (fusion-friendly XLA lowering) on
+every backend; ``pallas_interpret`` runs the Pallas kernel bodies in
+interpret mode to validate them in tests. ``pallas`` (the native
+kernels) raises before tracing, because the TPU compiler refuses them
+(``PALLAS_TPU_REFUSAL``).
 
 Exactness: the compacted candidate set provably covers the true top-k
 unless the per-row/per-block witnesses say otherwise (or a boundary tie
@@ -69,14 +70,38 @@ import jax.numpy as jnp
 
 from repro.core.flatten import bucket_bounds
 from repro.core.numerics import safe_denom
-from repro.kernels.common import auto_interpret
 from repro.kernels.compress import kernel as pk
 from repro.kernels.compress import xla as px
 from repro.kernels.compress.dispatch import hist_capacity
 
 
+PALLAS_TPU_REFUSAL = (
+    "strategy='pallas' (native Pallas compress kernels) does not compile "
+    "for TPU: the v5e compiler refuses their (1, BLOCK), (1, 1), "
+    "(1, bins) and (1, maxpb) blocks (the last two block dimensions must "
+    "be divisible by 8 and 128), and Pallas TPU lowering has no rule for "
+    "the in-kernel scatter-add histogram, the cumsum compaction, the "
+    ".at[].set slot scatters or the sketch scatter-add. Use "
+    "strategy='xla' (the default), or 'pallas_interpret' to run the "
+    "kernel bodies in interpret mode.")
+
+
 def default_strategy() -> str:
-    return "xla" if auto_interpret() else "pallas"
+    """The strategy every backend runs: ``xla`` compiles for the TPU and
+    the CPU alike."""
+    return "xla"
+
+
+def resolve_strategy(strategy: Optional[str]) -> str:
+    """``strategy`` or the default, validated before anything traces.
+    Only ``pallas_interpret`` interprets; ``pallas`` raises
+    ``NotImplementedError(PALLAS_TPU_REFUSAL)``."""
+    strategy = strategy or default_strategy()
+    if strategy == "pallas":
+        raise NotImplementedError(PALLAS_TPU_REFUSAL)
+    if strategy not in ("xla", "pallas_interpret"):
+        raise ValueError(f"unknown strategy {strategy!r}")
+    return strategy
 
 
 def sweep_plan(pipeline: str, comm_mode: str = "sparse") -> dict:
@@ -122,6 +147,19 @@ def _posterior_keys(a_sel, a_prev_sel, g_prev_sel, step, *,
     if support_valid is not None:
         skey = jnp.where(support_valid, skey, -jnp.inf)
     return skey
+
+
+def _offsupport_reg(step, Q, mu):
+    """REGTOP-k's off-support regularizer tanh(|1 + Q| / mu), 1 at step 0.
+
+    Evaluated on the device, as the reference evaluates it per element:
+    folded on the host at compile time, it can differ from the device's
+    tanh in the last bit (it does on a TPU v5e), and that one-ulp scale
+    between support and off-support scores reorders their ties at the
+    selection boundary."""
+    q = jax.lax.optimization_barrier(jnp.float32(Q))
+    return jnp.where(step == 0, jnp.float32(1.0),
+                     jnp.tanh(jnp.abs(1.0 + q) / mu))
 
 
 def _scalar_select(pred, x, y):
@@ -189,8 +227,7 @@ def _sweep1_xla(kind, g, err_prev, c, *, momentum, mom, gate=None):
     return a, a * c, mom_out
 
 
-def _sweep1_slice(kind, g_s, err_s, c, *, momentum, mom_s,
-                  interpret, gate=None):
+def _sweep1_slice(kind, g_s, err_s, c, *, momentum, mom_s, gate=None):
     """One padded-slice sweep-1 launch over PRE-SLICED inputs, shared by
     the bucketed global path, the allocated per-segment path, and the
     streaming path (whose ``g_s`` arrives as a standalone segment array
@@ -209,27 +246,27 @@ def _sweep1_slice(kind, g_s, err_s, c, *, momentum, mom_s,
         pad(g_s), pad(err_s), c,
         mode=("dgc" if dgc else "plain"), momentum=momentum,
         mom=None if mom_s is None else pad(mom_s),
-        gate=gate if dgc else None, interpret=interpret)
+        gate=gate if dgc else None, interpret=True)
     # padding contributed (j_pad - size) zero keys to bin 0
     return (a_p[:size], score_p, mom_p[:size] if dgc else None,
             hist.at[0].add(-(j_pad - size)))
 
 
-def _sweep2_slice(score_p, tau, off, size, maxpb: int, interpret):
+def _sweep2_slice(score_p, tau, off, size, maxpb: int):
     """One slice sweep-2 compaction (shared like _sweep1_slice): kills
     slice-local padding slots BEFORE the global-offset shift (they must
     not alias the next slice's index range) and reports ok iff no block
     overflowed its maxpb candidate slots. Returns (cand_keys,
     cand_idx_global, ok)."""
     _mask_t, ck, ci, cnts = pk.sweep2_pallas(
-        score_p, tau, maxpb=maxpb, interpret=interpret, want_mask=False)
+        score_p, tau, maxpb=maxpb, interpret=True, want_mask=False)
     ck = jnp.where(ci < size, ck, -jnp.inf)
     return ck, ci + jnp.uint32(off), jnp.max(cnts) <= maxpb
 
 
 def _candidates_pallas(kind, g, err_prev, c, step, *, k: int,
-                       regtopk: bool, momentum: float, mom, interpret: bool,
-                       bounds, gate=None, g_segments=None):
+                       regtopk: bool, momentum: float, mom, bounds,
+                       gate=None, g_segments=None):
     """Per-bucket Pallas sweeps + histogram-merge global threshold.
 
     Sweep 1 runs once per bucket and emits that bucket's 2048-bin
@@ -254,7 +291,7 @@ def _candidates_pallas(kind, g, err_prev, c, step, *, k: int,
         a_p, score_p, mom_p, hist = _sweep1_slice(
             kind, g_s, err_prev[off:off + size], c, momentum=momentum,
             mom_s=None if mom is None else mom[off:off + size],
-            interpret=interpret, gate=gate)
+            gate=gate)
         hists.append(hist)
         a_parts.append(a_p)
         score_parts.append(score_p)
@@ -269,8 +306,7 @@ def _candidates_pallas(kind, g, err_prev, c, step, *, k: int,
     maxpb = int(min(pk.BLOCK, max(32, -(-8 * k * pk.BLOCK // j))))
     ck_parts, ci_parts, oks = [], [], []
     for (off, size), score_p in zip(bounds, score_parts):
-        ck, ci, ok_b = _sweep2_slice(score_p, tau, off, size, maxpb,
-                                     interpret)
+        ck, ci, ok_b = _sweep2_slice(score_p, tau, off, size, maxpb)
         ci_parts.append(ci)
         ck_parts.append(ck)
         oks.append(ok_b)
@@ -426,14 +462,13 @@ def fused_sketch_encode(g, err_prev, *, rows: int, width: int,
 
     Budget (audit.py absolutes, pinned in tests/test_sketch.py):
 
-    - strategy="pallas": ONE combined kernel emits a and the sketch in a
-      single pass — 1.0 traversal, 1.0 J-sized write.
-    - strategy="xla": the elementwise a-stream (XLA-fused) plus a
-      dedicated encode kernel reading a once — 2.0 traversals, 1.0
-      J-sized write. The kernel route is load-bearing: an XLA
-      ``.at[h].add`` encode bills one extra traversal PER ROW (rows
-      scatter barriers), and the legacy vmap encode materializes
-      (rows, J) hash/sign intermediates; both blow the 2.0 budget.
+    - strategy="pallas_interpret": ONE combined kernel emits a and the
+      sketch in a single pass — 1.0 traversal, 1.0 J-sized write.
+    - strategy="xla": the elementwise a-stream (XLA-fused) plus
+      ``core.sketch.encode``, whose ``.at[h].add`` scatters bill one
+      traversal PER ROW and whose (rows, J) hash/sign intermediates
+      are J-sized writes, so it audits above the kernel's 2.0 budget
+      (tests/test_sketch.py pins both counts).
 
     The (rows, width) sketch output is below the audit's sizable floor
     at every bench shape (width ~ 4k << J/16), so the encode adds no
@@ -443,30 +478,25 @@ def fused_sketch_encode(g, err_prev, *, rows: int, width: int,
     all-reduce, this just keeps the EF stream bit-comparable.
     """
     from repro.core import sketch as core_sketch
-    strategy = strategy or default_strategy()
+    strategy = resolve_strategy(strategy)
     if participate is not None:
         g, err_prev, _pf = masked_inputs(g, err_prev, participate,
                                          err_decay)
-    mults = tuple(int(x) for x in core_sketch._MULTS[:rows])
-    adds = tuple(int(x) for x in core_sketch._ADDS[:rows])
-    if strategy in ("pallas", "pallas_interpret"):
+    if strategy == "pallas_interpret":
         a, sk = pk.sweep1_sketch_pallas(
-            g, err_prev, rows=rows, width=width, mults=mults, adds=adds,
-            interpret=strategy != "pallas")
-    elif strategy == "xla":
-        a = err_prev.astype(jnp.float32) + g.astype(jnp.float32)
-        sk = pk.sketch_encode_pallas(a, rows=rows, width=width,
-                                     mults=mults, adds=adds,
-                                     interpret=True)
+            g, err_prev, rows=rows, width=width,
+            mults=tuple(int(x) for x in core_sketch._MULTS[:rows]),
+            adds=tuple(int(x) for x in core_sketch._ADDS[:rows]),
+            interpret=True)
     else:
-        raise ValueError(f"unknown strategy {strategy!r}")
+        a = err_prev.astype(jnp.float32) + g.astype(jnp.float32)
+        sk = core_sketch.encode(a, rows, width)
     return {"a": a, "sketch": sk}
 
 
 def _seg_candidates_pallas(kind, g, err_prev, c, step, *, provs, k: int,
-                           regtopk: bool, momentum: float, mom,
-                           interpret: bool, bounds, gate=None,
-                           g_segments=None):
+                           regtopk: bool, momentum: float, mom, bounds,
+                           gate=None, g_segments=None):
     """Per-SEGMENT Pallas sweeps for allocation != "global" (DESIGN.md
     §2.6): unlike the bucketed global path (one merged-histogram tau),
     each segment's sweep-1 histogram picks its OWN threshold at target
@@ -486,7 +516,7 @@ def _seg_candidates_pallas(kind, g, err_prev, c, step, *, provs, k: int,
         a_p, score_p, mom_p, hist = _sweep1_slice(
             kind, g_s, err_prev[off:off + size], c, momentum=momentum,
             mom_s=None if mom is None else mom[off:off + size],
-            interpret=interpret, gate=gate)
+            gate=gate)
         # support corrections may drop <= min(k, size) in-segment entries
         # below tau without breaking coverage of the segment's top-prov
         target = provs[pos] + jnp.where(
@@ -494,8 +524,7 @@ def _seg_candidates_pallas(kind, g, err_prev, c, step, *, provs, k: int,
         tau = pk.threshold_from_hist(hist, target)
         maxpb = int(min(pk.BLOCK,
                         max(32, -(-8 * provs[pos] * pk.BLOCK // size))))
-        ck, ci, ok_b = _sweep2_slice(score_p, tau, off, size, maxpb,
-                                     interpret)
+        ck, ci, ok_b = _sweep2_slice(score_p, tau, off, size, maxpb)
         ck_parts.append(ck)
         ci_parts.append(ci)
         ok_parts.append(ok_b)
@@ -617,17 +646,12 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
         trim_caps = caps
         slack = 2.0
     regtopk = kind == "regtopk"
-    if regtopk:
-        c = jnp.where(step == 0, jnp.float32(1.0),
-                      jnp.tanh(jnp.abs(1.0 + jnp.float32(Q)) / mu))
-    else:
-        c = jnp.float32(1.0)
+    c = _offsupport_reg(step, Q, mu) if regtopk else jnp.float32(1.0)
 
-    if strategy in ("pallas", "pallas_interpret"):
-        interpret = strategy == "pallas_interpret" or auto_interpret()
+    if strategy == "pallas_interpret":
         a, mom_out, ck_parts, ci_parts, ok_parts = _seg_candidates_pallas(
             kind, g, err_prev, c, step, provs=provs, k=k, regtopk=regtopk,
-            momentum=momentum, mom=mom, interpret=interpret, bounds=bounds,
+            momentum=momentum, mom=mom, bounds=bounds,
             gate=gate, g_segments=g_segments)
         wit_parts = None
         ok = ok_parts[0]
@@ -875,7 +899,7 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
       ``seg_bounds``.
     """
     from repro.core import bigvec
-    strategy = strategy or default_strategy()
+    strategy = resolve_strategy(strategy)
     streaming = g_segments is not None
     if streaming:
         assert g is None, "streaming: pass g_segments, not a flat g"
@@ -929,17 +953,12 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
     # comm-side chunking of the packed pairs (core.aggregate)
     bounds = stream_bounds if streaming else bucket_bounds(j, num_buckets)
     regtopk = kind == "regtopk"
-    if regtopk:
-        c = jnp.where(step == 0, jnp.float32(1.0),
-                      jnp.tanh(jnp.abs(1.0 + jnp.float32(Q)) / mu))
-    else:
-        c = jnp.float32(1.0)
+    c = _offsupport_reg(step, Q, mu) if regtopk else jnp.float32(1.0)
 
-    if strategy in ("pallas", "pallas_interpret"):
-        interpret = strategy == "pallas_interpret" or auto_interpret()
+    if strategy == "pallas_interpret":
         a, mom_out, cand_k, cand_i, producer_ok = _candidates_pallas(
             kind, g, err_prev, c, step, k=kcap, regtopk=regtopk,
-            momentum=momentum, mom=mom, interpret=interpret, bounds=bounds,
+            momentum=momentum, mom=mom, bounds=bounds,
             gate=gate, g_segments=g_segments)
         witnesses = None
     else:

@@ -272,7 +272,7 @@ def dryrun_one(arch: str, shape_name: str, mesh, *, sparsifier="regtopk",
                 fault_rec["sparse_gather_wire_bytes_active"] = float(gw_act)
     t0 = time.time()
     step, abs_args, pal = build_step(run, mesh, kind)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step).lower(*abs_args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -362,6 +362,8 @@ def dryrun_one(arch: str, shape_name: str, mesh, *, sparsifier="regtopk",
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

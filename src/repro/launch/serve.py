@@ -44,10 +44,13 @@ def main(argv=None):
     args = parse_args(argv)
     if args.devices:
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+            os.environ.get("XLA_FLAGS", "") +
+            f" --xla_force_host_platform_device_count={args.devices}")
     import dataclasses
 
     import jax
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     import jax.numpy as jnp
     from repro.configs.base import (RunConfig, SHAPES, SparsifierConfig,
                                     get_config, reduced_config)
@@ -74,7 +77,7 @@ def main(argv=None):
     mesh = make_mesh(args.data, args.model)
     pal = serve_parallel(mesh, run, decode=True)
     key = jax.random.PRNGKey(args.seed)
-    with mesh:
+    with jax.set_mesh(mesh):
         tmpl_pal = pal
         pspecs = param_specs(
             jax.eval_shape(lambda k: init_params(cfg, tmpl_pal, k), key)) \

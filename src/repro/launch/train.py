@@ -182,25 +182,17 @@ def resolve_delta_fault_spec(args) -> str:
     return spec
 
 
-def main(argv=None):
-    args = parse_args(argv)
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
-    import jax
+def build_run(args):
+    """The RunConfig the parsed flags describe (chip_smoke.py builds its
+    runs through this too, so both train the same configuration)."""
     from repro.configs.base import (OptimizerConfig, RunConfig, SHAPES,
                                     SparsifierConfig, get_config,
                                     reduced_config)
-    from repro.data import lm_batch
-    from repro.launch.mesh import make_mesh
-    from repro.train.step import (build_parallel, build_train_step,
-                                  init_train_state, resolve_model_cfg)
-
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduced_config(cfg)
     fault_spec = resolve_fault_spec(args)
-    run = RunConfig(
+    return RunConfig(
         model=cfg, shape=SHAPES["train_4k"],
         sparsifier=SparsifierConfig(kind=args.sparsifier,
                                     sparsity=args.sparsity, mu=args.mu,
@@ -222,11 +214,39 @@ def main(argv=None):
         checkpoint_every=args.checkpoint_every,
         fault_schedule=fault_spec,
     )
+
+
+def compress_strategy(sp) -> str:
+    """The compress strategy a SparsifierConfig runs, for banners."""
+    if sp.kind == "none":
+        return "none (dense sync)"
+    if sp.pipeline != "fused":
+        return "reference (dense jnp oracle)"
+    from repro.kernels.compress.ops import default_strategy
+    return default_strategy()
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.devices:
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") +
+            f" --xla_force_host_platform_device_count={args.devices}")
+    import jax
+    from repro.data import lm_batch
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
+    from repro.train.step import (build_parallel, build_train_step,
+                                  init_train_state, resolve_model_cfg)
+
+    enable_compile_cache()
+    run = build_run(args)
+    cfg = run.model
     mesh = make_mesh(args.data, args.model, args.pods)
     pal = build_parallel(mesh)
     mcfg = resolve_model_cfg(run)
     key = jax.random.PRNGKey(args.seed)
-    with mesh:
+    with jax.set_mesh(mesh):
         params, opt_state, ef_state = init_train_state(run, mesh, pal, key)
         step, _, _ = build_train_step(run, mesh, pal)
         jstep = jax.jit(step, donate_argnums=(0, 1, 2))
@@ -243,7 +263,8 @@ def main(argv=None):
             nb, j_local, dp = auto_num_buckets_for_run(run, mesh, pal)
             print(f"[train] num_buckets=0 -> auto-tuned {nb} "
                   f"(J_local={j_local:,}, dp={dp})")
-        print(f"[train] effective comm mode: {effective_comm_mode(sp)}")
+        print(f"[train] effective comm mode: {effective_comm_mode(sp)}, "
+              f"compress strategy: {compress_strategy(sp)}")
         if sp.overlap == "backward":
             from repro.train.step import stream_bounds_for_run
             sb = stream_bounds_for_run(run, mesh, pal)

@@ -29,6 +29,7 @@ from repro.configs.base import (get_config, reduced_config, RunConfig,
                                 SparsifierConfig, OptimizerConfig, SHAPES)
 from repro.train.step import build_parallel, build_train_step, init_train_state
 from repro.data import lm_batch
+from repro.launch.mesh import make_mesh
 
 def make_run(arch, sp_kind="regtopk", comm="simulate", opt="adam", sparsity=0.05):
     cfg = reduced_config(get_config(arch))
@@ -43,10 +44,10 @@ def make_run(arch, sp_kind="regtopk", comm="simulate", opt="adam", sparsity=0.05
 def train(run, mesh_shape, steps=3, key_seed=0, fixed_batch=False):
     # fixed_batch: uniform-random token streams carry no cross-batch signal;
     # convergence assertions must overfit one batch to be meaningful
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(*mesh_shape)
     pal = build_parallel(mesh)
     key = jax.random.PRNGKey(key_seed)
-    with mesh:
+    with jax.set_mesh(mesh):
         params, opt_state, ef_state = init_train_state(run, mesh, pal, key)
         step, _, _ = build_train_step(run, mesh, pal)
         jstep = jax.jit(step)
@@ -93,10 +94,10 @@ def test_tp_matches_single_device(arch):
 from repro.models import Parallel, loss_fn
 run = make_run("{arch}", sp_kind="none", opt="sgd")
 run = dataclasses.replace(run, optimizer=OptimizerConfig(kind="sgd", lr=1e-2))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh(2, 4)
 pal = build_parallel(mesh)
 key = jax.random.PRNGKey(0)
-with mesh:
+with jax.set_mesh(mesh):
     params, opt_state, ef_state = init_train_state(run, mesh, pal, key)
     step, _, _ = build_train_step(run, mesh, pal)
     batch = lm_batch(run.model, 8, 64, 0, 0)
@@ -161,10 +162,10 @@ from repro.models.specs import param_specs
 run = make_run("granite-8b", sp_kind="none")
 run = dataclasses.replace(run, shape=dataclasses.replace(
     SHAPES["decode_32k"], seq_len=64, global_batch=8))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh(4, 2)
 pal = serve_parallel(mesh, run, decode=True)
 assert pal.cache_seq_axis is None
-with mesh:
+with jax.set_mesh(mesh):
     tmpl = __import__("repro.train.step", fromlist=["x"]).abstract_params(run, pal)
     pspecs = param_specs(tmpl)
     def init_fn(k):
@@ -213,7 +214,7 @@ from repro.models.specs import param_specs
 run = make_run("granite-8b", sp_kind="none")
 run = dataclasses.replace(run, shape=dataclasses.replace(
     SHAPES["long_500k"], seq_len=64, global_batch=1))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh(4, 2)
 pal = serve_parallel(mesh, run, decode=True)
 assert pal.cache_seq_axis == "data"
 # single-device reference prefill builds the cache; shard it onto the mesh
@@ -226,9 +227,9 @@ tok = jnp.argmax(lg1, -1)[:, None].astype(jnp.int32)
 lg_ref, _ = mdecode(params1, c1, tok, run.model, pal1)
 
 # sharded: tp=1 on model axis? use (4,1) mesh to isolate ctx-parallel over data
-mesh = jax.make_mesh((4, 1), ("data", "model"))
+mesh = make_mesh(4, 1)
 pal = serve_parallel(mesh, run, decode=True)
-with mesh:
+with jax.set_mesh(mesh):
     dec, (pspecs, cspecs, tok_spec) = build_decode_step(run, mesh, pal)
     cache_sharded = jax.device_put(c1, jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), cspecs))
@@ -246,10 +247,10 @@ def test_multipod_mesh_small():
     """3-axis (pod, data, model) mesh trains and matches 2-axis semantics."""
     out = run_py(COMMON + """
 run = make_run("stablelm-3b", sp_kind="topk", comm="sparse", sparsity=0.1)
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = make_mesh(2, 2, pods=2)
 pal3 = build_parallel(mesh3)
 key = jax.random.PRNGKey(0)
-with mesh3:
+with jax.set_mesh(mesh3):
     params, opt_state, ef_state = init_train_state(run, mesh3, pal3, key)
     step, _, _ = build_train_step(run, mesh3, pal3)
     jstep = jax.jit(step)
@@ -392,7 +393,7 @@ def test_delta_apply_sharded_with_psum_health_guard():
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.serve.delta import DeltaApplier, DeltaPublisher, payload_health
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh(4, 2)
 key = jax.random.PRNGKey(0)
 host = {"w": jax.random.normal(key, (16, 8)),
         "b": jax.random.normal(jax.random.fold_in(key, 1), (64,))}

@@ -47,6 +47,21 @@ def test_train_launcher_allocation_smoke(tmp_path):
                    "--log-every", "4", "--fixed-batch"])
     losses = [float(m) for m in re.findall(r"loss (\d+\.\d+)", out)]
     assert losses and losses[-1] < losses[0]
+    assert "compress strategy: xla" in out
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """chip_smoke.py exits non-zero with no result line when JAX finds no
+    TPU, and stops before it builds the model."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=ROOT,
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert "[smoke] config" not in out.stdout
+    assert re.search(r"needs 1 TPU chip\(s\); JAX found \d+ cpu device",
+                     out.stderr), out.stderr
 
 
 def test_dryrun_tiny_mesh(tmp_path):
