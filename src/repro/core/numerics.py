@@ -13,8 +13,10 @@ TINY = 1e-12
 
 
 def safe_denom(denom, tiny: float = TINY):
-    """Zero-safe divisor: |denom| <= tiny is replaced by
-    sign(denom)*tiny + tiny (positive for denom >= 0, the REGTOP-k
-    Algorithm 1 line 5 convention)."""
+    """Zero-safe divisor for REGTOP-k's Algorithm 1 line 5: |denom| <=
+    tiny is replaced by -tiny for a negative denom and by +tiny
+    otherwise. It is never zero: a zero divisor turns the posterior
+    distortion into inf or NaN (0 * inf off the support of s^{t-1}),
+    and a NaN score outranks every finite one in top_k."""
     return jnp.where(jnp.abs(denom) > tiny, denom,
-                     jnp.sign(denom) * tiny + tiny)
+                     jnp.where(denom < 0, -tiny, tiny))

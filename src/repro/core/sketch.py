@@ -71,15 +71,21 @@ def resolve_width(k: int, width: int = 0) -> int:
     return int(w)
 
 
-def _hashes(j: int, rows: int, width: int):
-    """(h (rows, J) bucket indices, s (rows, J) ±1 signs), stateless."""
-    idx = jnp.arange(j, dtype=jnp.uint32)
-    m = _MULTS[:rows, None]
-    a = _ADDS[:rows, None]
-    x = idx[None, :] * m + a
-    h = (x >> 8).astype(jnp.uint32) % jnp.uint32(width)
+def hash_row(idx, mult: int, add: int, width: int):
+    """One row's CountSketch hash of a uint32 index stream: (h int32
+    bucket indices, s ±1 fp32 signs). The one formula that encode,
+    estimate and the fused kernel's encode share; ``mult``/``add`` are
+    python ints, so a kernel body can call it."""
+    x = idx * jnp.uint32(mult) + jnp.uint32(add)
+    h = jax.lax.rem(x >> 8, jnp.uint32(width)).astype(jnp.int32)
     s = ((x >> 31) & 1).astype(jnp.float32) * 2.0 - 1.0
-    return h.astype(jnp.int32), s
+    return h, s
+
+
+def _row_hashes(j: int, rows: int, width: int):
+    idx = jnp.arange(j, dtype=jnp.uint32)
+    return [hash_row(idx, int(_MULTS[r]), int(_ADDS[r]), width)
+            for r in range(rows)]
 
 
 def encode(a: jnp.ndarray, rows: int, width: int) -> jnp.ndarray:
@@ -88,20 +94,14 @@ def encode(a: jnp.ndarray, rows: int, width: int) -> jnp.ndarray:
     One 1D scatter-add per row, hashing the index stream row by row, so
     no (rows, J) hash/sign array is built: each row costs one J-sized
     scatter pass over ``a``."""
-    idx = jnp.arange(a.shape[0], dtype=jnp.uint32)
     af = a.astype(jnp.float32)
-    out = []
-    for r in range(rows):
-        x = idx * _MULTS[r] + _ADDS[r]
-        h = jax.lax.rem(x >> 8, jnp.uint32(width)).astype(jnp.int32)
-        s = ((x >> 31) & 1).astype(jnp.float32) * 2.0 - 1.0
-        out.append(jnp.zeros((width,), jnp.float32).at[h].add(s * af))
-    return jnp.stack(out)
+    return jnp.stack([jnp.zeros((width,), jnp.float32).at[h].add(s * af)
+                      for h, s in _row_hashes(a.shape[0], rows, width)])
 
 
 def estimate(sketch: jnp.ndarray, j: int) -> jnp.ndarray:
     """Magnitude estimates for all J entries (median over rows)."""
     rows, width = sketch.shape
-    h, s = _hashes(j, rows, width)
-    vals = jax.vmap(lambda skr, hr, sr: sr * skr[hr])(sketch, h, s)
+    vals = jnp.stack([s * sketch[r][h] for r, (h, s)
+                      in enumerate(_row_hashes(j, rows, width))])
     return jnp.median(vals, axis=0)

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.sketch import hash_row
+
 BLOCK = 8 * 128 * 4      # 4096 fp32 elements per grid step, VMEM tile-aligned
 BINS = 2048              # 2^11 bit-pattern bins: exponent + 3 mantissa bits
 _BIN_SHIFT = 20          # fp32 bits >> 20 -> [0, 2047] for non-negative floats
@@ -308,9 +310,9 @@ SKETCH_BLOCK = 32 * BLOCK
 def _sketch_accum(a, base, sk_ref, *, rows: int, width: int, block: int,
                   mults, adds):
     """Accumulate one (block,) slice of ``a`` into the (rows, width)
-    sketch ref. Hashing is BIT-identical to core.sketch._hashes: the
-    uint32 index stream through the same multiplicative-hash constants
-    (baked as python ints — kernels must not capture arrays).
+    sketch ref. Hashing is core.sketch.hash_row itself, over the uint32
+    index stream with the row constants baked as python ints (kernels
+    must not capture arrays).
 
     Each row scatters into its own 1D (width,) accumulator: XLA lowers
     a 1D scatter-add measurably faster than the batched/2D form the
@@ -319,9 +321,7 @@ def _sketch_accum(a, base, sk_ref, *, rows: int, width: int, block: int,
     lane = jax.lax.broadcasted_iota(jnp.uint32, (block,), 0)
     gidx = base + lane                       # uint32 global element index
     for r in range(rows):
-        x = gidx * jnp.uint32(mults[r]) + jnp.uint32(adds[r])
-        h = ((x >> 8) % jnp.uint32(width)).astype(jnp.int32)
-        s = ((x >> 31) & 1).astype(jnp.float32) * 2.0 - 1.0
+        h, s = hash_row(gidx, mults[r], adds[r], width)
         sk_ref[r, :] += jnp.zeros((width,), jnp.float32).at[h].add(s * a)
 
 

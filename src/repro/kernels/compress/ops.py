@@ -465,10 +465,10 @@ def fused_sketch_encode(g, err_prev, *, rows: int, width: int,
     - strategy="pallas_interpret": ONE combined kernel emits a and the
       sketch in a single pass — 1.0 traversal, 1.0 J-sized write.
     - strategy="xla": the elementwise a-stream (XLA-fused) plus
-      ``core.sketch.encode``, whose ``.at[h].add`` scatters bill one
-      traversal PER ROW and whose (rows, J) hash/sign intermediates
-      are J-sized writes, so it audits above the kernel's 2.0 budget
-      (tests/test_sketch.py pins both counts).
+      ``core.sketch.encode``: one 1D scatter pass over ``a`` per row,
+      each with a J-sized hash and a J-sized update write of its own,
+      so it audits at 1 + rows traversals and 1 + 2 * rows write units,
+      above the kernel's budget (tests/test_sketch.py pins both).
 
     The (rows, width) sketch output is below the audit's sizable floor
     at every bench shape (width ~ 4k << J/16), so the encode adds no

@@ -22,8 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.numerics import safe_denom
+
 BLOCK = 8 * 128 * 4
-_TINY = 1e-12
 
 
 def _scores_kernel(g_ref, err_ref, a_prev_ref, g_agg_ref, s_prev_ref,
@@ -35,8 +36,7 @@ def _scores_kernel(g_ref, err_ref, a_prev_ref, g_agg_ref, s_prev_ref,
     s_prev = s_prev_ref[...].astype(jnp.float32)
     a = err + g
     denom = omega * a
-    safe = jnp.where(jnp.abs(denom) > _TINY, denom,
-                     jnp.sign(denom) * _TINY + _TINY)
+    safe = safe_denom(denom)
     delta_sent = (g_agg - omega * a_prev) / safe
     delta = s_prev * delta_sent + q * (1.0 - s_prev)
     reg = jnp.tanh(jnp.abs(1.0 + delta) / mu)

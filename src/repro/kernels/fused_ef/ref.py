@@ -4,15 +4,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-_TINY = 1e-12
+from repro.core.numerics import safe_denom
 
 
 def scores_ref(g, err, a_prev, g_agg, s_prev, *, omega, mu, q):
     g = g.astype(jnp.float32)
     a = err.astype(jnp.float32) + g
     denom = omega * a
-    safe = jnp.where(jnp.abs(denom) > _TINY, denom,
-                     jnp.sign(denom) * _TINY + _TINY)
+    safe = safe_denom(denom)
     delta_sent = (g_agg.astype(jnp.float32) - omega * a_prev.astype(jnp.float32)) / safe
     delta = s_prev * delta_sent + q * (1.0 - s_prev)
     reg = jnp.tanh(jnp.abs(1.0 + delta) / mu)

@@ -179,6 +179,34 @@ class TestAdversarial:
             cfg_r, cfg_f = _pair("regtopk", k=k, mu=0.5)
             _roundtrip(cfg_r, cfg_f, j=j, steps=3, seed=j)
 
+    @pytest.mark.parametrize("pipeline", ["reference", "fused"])
+    def test_tiny_negative_accumulator_one_worker_is_topk(self, pipeline):
+        """With one worker and Q = 0 every REGTOP-k score is a * tanh(1/mu),
+        so the selection is TOP-k's. Entries whose accumulator lands in
+        [-1e-12, 0) (off the previous support: entry 0; on it: entry 15)
+        must not win through a zero safe divisor's NaN score."""
+        j, k = 16, 2
+        cfg_t = SparsifierConfig(kind="topk", k=k, pipeline=pipeline)
+        cfg_r = SparsifierConfig(kind="regtopk", k=k, mu=0.5, Q=0.0,
+                                 pipeline=pipeline)
+        st = sparsify.init_state(cfg_r, j)
+        g0 = (0.1 * jnp.arange(1, j + 1, dtype=jnp.float32)).at[0].set(1e-13)
+        out = sparsify.compress(cfg_r, st, g0)
+        st = sparsify.observe_aggregate(cfg_r, out.state,
+                                        sparsify.dense_ghat(out, j))
+        err = g0 * (1.0 - sparsify.dense_mask(out, j))
+        a1 = (jnp.zeros((j,)).at[0].set(-5e-13).at[15].set(-5e-13)
+              .at[3].set(0.9).at[5].set(0.8))
+        g1 = a1 - err
+        assert float((err + g1)[0]) < 0 and float((err + g1)[15]) < 0
+        out_r = sparsify.compress(cfg_r, st, g1)
+        out_t = sparsify.compress(cfg_t, sparsify.init_state(cfg_t, j),
+                                  err + g1)
+        sel = np.flatnonzero(np.asarray(sparsify.dense_mask(out_r, j)))
+        assert sel.tolist() == [3, 5]
+        assert (sparsify.dense_mask(out_r, j) ==
+                sparsify.dense_mask(out_t, j)).all()
+
 
 def _roundtrip_static(cfg_r, cfg_f, g, steps=3, omega=0.5):
     j = g.shape[0]

@@ -441,31 +441,38 @@ def _run_faulty(spec, tmp_path, steps=25, snap_every=8, k=24):
     for p in chan.flush():
         app.offer(p)
     pub.write_snapshot(snap)
-    # a version lost at the very end has no successor to reveal the gap,
-    # so the end of the stream converges on the final snapshot
-    if app.can_resync(snap):
+    if app.needs_resync and app.can_resync(snap):
         app.resync_from(snap)
     drain(chan, app)
+    stream = dict(app.counters)
+    # versions lost at the very end have no successor to reveal the gap:
+    # only when the channel dropped or corrupted EVERY version after the
+    # held one may the replica catch up from the final snapshot
+    tail = range(app.version + 1, pub.version + 1)
+    if tail and all(bool(faults.channel_drops(chan.sched, v)) or
+                    bool(faults.channel_corrupts(chan.sched, v))
+                    for v in tail):
+        app.resync_from(snap)
     _assert_trees_equal(app.params, pub.params_at(app.version), msg=spec)
     assert app.version == pub.version, (spec, app.metrics())
-    return app, chan
+    return app, chan, stream
 
 
 def test_invariant_under_loss(tmp_path):
-    app, chan = _run_faulty("loss:0.4,seed=2", tmp_path)
+    app, chan, stream = _run_faulty("loss:0.4,seed=2", tmp_path)
     assert chan.counters["dropped"] > 0
-    assert app.counters["gaps_detected"] > 0 and app.counters["resyncs"] > 0
+    assert stream["gaps_detected"] > 0 and stream["resyncs"] > 0
 
 
 def test_invariant_under_corruption(tmp_path):
-    app, chan = _run_faulty("corrupt:0.4,seed=3", tmp_path)
+    app, chan, stream = _run_faulty("corrupt:0.4,seed=3", tmp_path)
     assert chan.counters["corrupted"] > 0
     assert app.counters["dropped_corrupt"] == chan.counters["corrupted"]
-    assert app.counters["resyncs"] > 0
+    assert stream["gaps_detected"] > 0 and stream["resyncs"] > 0
 
 
 def test_invariant_under_reorder(tmp_path):
-    app, chan = _run_faulty("reorder:3,seed=4", tmp_path)
+    app, chan, _ = _run_faulty("reorder:3,seed=4", tmp_path)
     assert chan.counters["delayed"] > 0
     # reorder delivers everything eventually; anything early is stale
     # or gapped, never applied out of order
@@ -475,7 +482,7 @@ def test_invariant_under_reorder(tmp_path):
 def test_invariant_under_stall_no_resync(tmp_path):
     """A paused link flushes IN ORDER: the replica absorbs the backlog
     with zero gaps and zero resyncs."""
-    app, chan = _run_faulty("stall:5,at=3", tmp_path)
+    app, chan, _ = _run_faulty("stall:5,at=3", tmp_path)
     assert chan.counters["stalled"] > 0
     assert app.counters["gaps_detected"] == 0
     assert app.counters["resyncs"] == 0

@@ -150,3 +150,22 @@ def test_comm_volume_model():
     v = comm_bytes_per_step(cfg, j, n)
     assert v["ratio"] < 0.05          # >20x reduction at S=0.1%
     assert v["bytes"] == n * v["k"] * 8
+
+
+@pytest.mark.parametrize("denom", [-1e-12, -5e-13, -1e-40, -0.0, 0.0,
+                                   5e-13, 1e-12])
+def test_safe_denom_never_zero_keeps_sign(denom):
+    from repro.core.numerics import TINY, safe_denom
+    d = jnp.float32(denom)
+    s = float(safe_denom(d))
+    assert abs(s) == float(jnp.float32(TINY))
+    if abs(denom) >= np.finfo(np.float32).tiny:
+        # a subnormal denom may be flushed to zero first (then +TINY)
+        assert (s < 0) == (denom < 0)
+    assert np.isfinite(float(jnp.float32(0.1) / safe_denom(d)))
+
+
+def test_safe_denom_passes_large_values():
+    from repro.core.numerics import safe_denom
+    d = jnp.asarray([-2.0, -1.1e-12, 1.1e-12, 3.0], jnp.float32)
+    assert (safe_denom(d) == d).all()
