@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SparsifierConfig
-from repro.core import sketch, sparsify
+from repro.core import sketch, sparsify, stages
 from repro.kernels.compress.dispatch import (  # noqa: F401  (re-export)
     dispatch as compress_dispatch,
     effective_comm_mode,
@@ -284,7 +284,9 @@ class GradientSync:
       allocation-invariant.
     - ``participate`` elastic liveness (§2.7): inert payloads, EF decay,
       active-set normalization, non-finite payload demotion,
-      ``with_stats`` health counters as rank-identical psums.
+      ``with_stats`` health counters as rank-identical psums, beside
+      the trim counters ``topk_fallback`` / ``topk_saturated_rows``
+      averaged over the ranks.
     """
 
     def __init__(self, cfg: SparsifierConfig, axes,
@@ -343,7 +345,7 @@ class GradientSync:
         n = _axis_size(axes)
         zero = jnp.zeros((), jnp.float32)
 
-        def _ret(g_agg, new_state, p_eff, dropped_local):
+        def _ret(g_agg, new_state, p_eff, dropped_local, trim=None):
             if not with_stats:
                 return g_agg, new_state
             if p_eff is None:
@@ -354,6 +356,12 @@ class GradientSync:
                                                   axes),
                          "dropped_nonfinite": jax.lax.psum(dropped_local,
                                                            axes)}
+            # the trim's counters (ops.trim_counters; zeros where no fused
+            # trim ran), averaged over the ranks: the share whose trim fell
+            # back and their mean count of saturated candidate rows
+            trim = trim or {"topk_fallback": zero,
+                            "topk_saturated_rows": zero}
+            stats.update({k: jax.lax.pmean(v, axes) for k, v in trim.items()})
             return g_agg, new_state, stats
 
         d = compress_dispatch(cfg)
@@ -404,51 +412,50 @@ class GradientSync:
             p_eff = p & finite
             dropped = (p & ~finite).astype(jnp.float32)
         elastic = p is not None or cfg.combine != "mean"
-        if cfg.comm_mode == "sparse" and out.values is not None:
-            if elastic:
-                g_agg = sparse_allgather_combine(out.values, out.indices,
-                                                 j, axes,
-                                                 num_buckets=cfg.num_buckets,
-                                                 wire_dtype=cfg.wire_dtype,
-                                                 participate=p_eff,
-                                                 count=out.count,
-                                                 combine=cfg.combine)
+        with stages.scope("exchange"):
+            if cfg.comm_mode == "sparse" and out.values is not None:
+                extra = (dict(participate=p_eff, count=out.count,
+                              combine=cfg.combine) if elastic else {})
+                g_agg = sparse_allgather_combine(
+                    out.values, out.indices, j, axes,
+                    num_buckets=cfg.num_buckets, wire_dtype=cfg.wire_dtype,
+                    **extra)
             else:
-                g_agg = sparse_allgather_combine(out.values, out.indices,
-                                                 j, axes,
-                                                 num_buckets=cfg.num_buckets,
-                                                 wire_dtype=cfg.wire_dtype)
-        else:
-            if cfg.comm_mode == "sparse":
-                # explicit, not silent: this config emits no packed pairs,
-                # so the sparse path cannot run — warn once (trace time)
-                # and surface the realized mode via effective_comm_mode
-                _warn_sparse_degrade(cfg)
-            ghat = sparsify.dense_ghat(out, j)
-            if p is not None and out.values is None:
-                finite = jnp.all(jnp.isfinite(ghat.astype(jnp.float32)))
-                p_eff = p & finite
-                dropped = (p & ~finite).astype(jnp.float32)
-            if not elastic:
-                g_agg = simulate_allreduce(ghat, axes)
-            else:
-                pe = jnp.ones((), jnp.bool_) if p_eff is None else p_eff
-                dsum = jax.lax.psum(
-                    jnp.where(pe, ghat, jnp.zeros((), ghat.dtype)), axes)
-                if cfg.combine == "support":
-                    m = sparsify.dense_mask(out, j)
-                    cnts = jax.lax.psum(
-                        jnp.where(pe, m, jnp.zeros((), m.dtype)), axes)
-                    g_agg = jnp.where(
-                        cnts > 0,
-                        dsum / jnp.maximum(cnts, 1.0).astype(ghat.dtype),
-                        jnp.zeros((), ghat.dtype))
+                if cfg.comm_mode == "sparse":
+                    # explicit, not silent: this config emits no packed
+                    # pairs, so the sparse path cannot run — warn once
+                    # (trace time) and surface the realized mode via
+                    # effective_comm_mode
+                    _warn_sparse_degrade(cfg)
+                ghat = sparsify.dense_ghat(out, j)
+                if p is not None and out.values is None:
+                    finite = jnp.all(jnp.isfinite(
+                        ghat.astype(jnp.float32)))
+                    p_eff = p & finite
+                    dropped = (p & ~finite).astype(jnp.float32)
+                if not elastic:
+                    g_agg = simulate_allreduce(ghat, axes)
                 else:
-                    na = jax.lax.psum(pe.astype(jnp.float32), axes)
-                    g_agg = dsum / jnp.maximum(na, 1.0).astype(ghat.dtype)
+                    pe = (jnp.ones((), jnp.bool_) if p_eff is None
+                          else p_eff)
+                    dsum = jax.lax.psum(
+                        jnp.where(pe, ghat, jnp.zeros((), ghat.dtype)),
+                        axes)
+                    if cfg.combine == "support":
+                        m = sparsify.dense_mask(out, j)
+                        cnts = jax.lax.psum(
+                            jnp.where(pe, m, jnp.zeros((), m.dtype)), axes)
+                        g_agg = jnp.where(
+                            cnts > 0,
+                            dsum / jnp.maximum(cnts, 1.0).astype(ghat.dtype),
+                            jnp.zeros((), ghat.dtype))
+                    else:
+                        na = jax.lax.psum(pe.astype(jnp.float32), axes)
+                        g_agg = dsum / jnp.maximum(na, 1.0).astype(
+                            ghat.dtype)
         new_state = sparsify.observe_aggregate(cfg, out.state, g_agg,
                                                participate=p_eff)
-        return _ret(g_agg, new_state, p_eff, dropped)
+        return _ret(g_agg, new_state, p_eff, dropped, out.trim)
 
     def _sync_sketch(self, cfg, d, state, g, p, n, _ret):
         """Sketch-coordinated global top-k step (DESIGN.md §2.9).

@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 
+from repro.core import stages
+
 
 class TreeFlattener:
     """Flattens a gradient pytree to one fp vector and back.
@@ -35,15 +37,17 @@ class TreeFlattener:
         leaves = jax.tree_util.tree_leaves(tree)
         if not leaves:
             return jnp.zeros((0,), self.dtype)
-        return jnp.concatenate(
-            [jnp.ravel(l).astype(self.dtype) for l in leaves])
+        with stages.scope("flatten"):
+            return jnp.concatenate(
+                [jnp.ravel(l).astype(self.dtype) for l in leaves])
 
     def unflatten(self, vec: jnp.ndarray):
         leaves = []
-        for off, size, shape, dt in zip(self.offsets, self.sizes,
-                                        self.shapes, self.dtypes):
-            leaves.append(jax.lax.dynamic_slice_in_dim(
-                vec, off, size).reshape(shape).astype(dt))
+        with stages.scope("unflatten"):
+            for off, size, shape, dt in zip(self.offsets, self.sizes,
+                                            self.shapes, self.dtypes):
+                leaves.append(jax.lax.dynamic_slice_in_dim(
+                    vec, off, size).reshape(shape).astype(dt))
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def flatten_segments(self, tree, bounds) -> list:
@@ -67,14 +71,16 @@ class TreeFlattener:
                     f"(leaf offsets: {self.offsets[li:li + 2]}...)")
             parts, have = [], 0
             while have < size:
-                parts.append(jnp.ravel(leaves[li]).astype(self.dtype))
+                parts.append(leaves[li])
                 have += self.sizes[li]
                 li += 1
             if have != size:
                 raise ValueError(
                     f"segment (off={off}, size={size}) cuts inside a leaf")
-            segs.append(parts[0] if len(parts) == 1
-                        else jnp.concatenate(parts))
+            with stages.scope("flatten"):
+                parts = [jnp.ravel(l).astype(self.dtype) for l in parts]
+                segs.append(parts[0] if len(parts) == 1
+                            else jnp.concatenate(parts))
         if li != len(leaves):
             raise ValueError("bounds do not cover every leaf")
         return segs

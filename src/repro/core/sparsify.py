@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SparsifierConfig
-from repro.core import select
+from repro.core import select, stages
 from repro.core.numerics import safe_denom
 
 
@@ -79,6 +79,9 @@ class CompressOut:
     indices: Optional[jnp.ndarray] = None  # (k,) uint32 indices
     count: Optional[jnp.ndarray] = None   # live packed slots (() int32);
                                           # None means all slots are live
+    trim: Optional[dict] = None  # the fused trim's counters
+                                 # (ops.trim_counters); None where no
+                                 # fused trim ran (reference pipeline)
 
 
 def resolve_k(cfg: SparsifierConfig, j: int) -> int:
@@ -615,8 +618,10 @@ def _compress_fused(cfg: SparsifierConfig, state: dict, g: jnp.ndarray,
                                           state["g_prev_sel"])
             if hist:
                 new["nsel"] = jnp.where(pf, out["count"], state["nsel"])
+    trim = {name: out[name]
+            for name in ("topk_fallback", "topk_saturated_rows")}
     return CompressOut(out["ghat"], None, new,
-                       out["values"], out["indices"], out["count"])
+                       out["values"], out["indices"], out["count"], trim)
 
 
 def observe_aggregate(cfg: SparsifierConfig, state: dict, g_agg: jnp.ndarray,
@@ -635,17 +640,19 @@ def observe_aggregate(cfg: SparsifierConfig, state: dict, g_agg: jnp.ndarray,
         pf = None if participate is None else jnp.asarray(participate,
                                                           jnp.bool_)
         from repro.kernels.compress.dispatch import dispatch
-        if dispatch(cfg).path == "fused" or cfg.state_format == "sparse":
-            # O(k) posterior: g^{t-1} is read only at the support of s^{t-1}
-            from repro.core import bigvec
-            gsel = bigvec.gather(g_agg, state["idx_prev"]).astype(
-                jnp.dtype(cfg.ef_dtype))
-            state["g_prev_sel"] = gsel if pf is None else jnp.where(
-                pf, gsel, state["g_prev_sel"])
-        else:
-            gobs = g_agg.astype(jnp.dtype(cfg.ef_dtype))
-            state["g_agg_prev"] = gobs if pf is None else jnp.where(
-                pf, gobs, state["g_agg_prev"])
+        with stages.scope("posterior"):
+            if dispatch(cfg).path == "fused" or cfg.state_format == "sparse":
+                # O(k) posterior: g^{t-1} is read only at the support of
+                # s^{t-1}
+                from repro.core import bigvec
+                gsel = bigvec.gather(g_agg, state["idx_prev"]).astype(
+                    jnp.dtype(cfg.ef_dtype))
+                state["g_prev_sel"] = gsel if pf is None else jnp.where(
+                    pf, gsel, state["g_prev_sel"])
+            else:
+                gobs = g_agg.astype(jnp.dtype(cfg.ef_dtype))
+                state["g_agg_prev"] = gobs if pf is None else jnp.where(
+                    pf, gobs, state["g_agg_prev"])
     return state
 
 

@@ -56,10 +56,14 @@ kernels) raises before tracing, because the TPU compiler refuses them
 
 Exactness: the compacted candidate set provably covers the true top-k
 unless the per-row/per-block witnesses say otherwise (or a boundary tie
-is ambiguous under REGTOP-k support corrections); those rare cases take
-a ``lax.cond`` fallback to a full ``lax.top_k`` with identical
-semantics. Fast path and fallback both reproduce the reference
-selector's tie-break support exactly.
+is ambiguous under REGTOP-k support corrections); those cases take a
+``lax.cond`` fallback to a full ``lax.top_k`` with identical semantics.
+Fast path and fallback both reproduce the reference selector's tie-break
+support exactly. Every score-based path returns two counters of its trim
+(``trim_counters``): ``topk_fallback`` (1.0 when the fallback ran) and
+``topk_saturated_rows`` (on the ``xla`` strategy, the candidate rows
+whose W-th key reached the selection threshold: the cover witness's
+failures; 0 where a path has no row witness).
 """
 from __future__ import annotations
 
@@ -68,6 +72,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import stages
 from repro.core.flatten import bucket_bounds
 from repro.core.numerics import safe_denom
 from repro.kernels.compress import kernel as pk
@@ -178,6 +183,15 @@ def _scalar_select(pred, x, y):
     return jax.lax.select(p, x, y)
 
 
+def trim_counters(ok, saturated_rows) -> dict:
+    """The trim's per-step counters, float32 scalars: ``topk_fallback``
+    1.0 when the ``lax.cond`` fallback ran (``ok`` False), else 0;
+    ``topk_saturated_rows`` the candidate rows that failed the cover
+    witness (W-th key at or above the selection threshold)."""
+    return {"topk_fallback": 1.0 - jnp.asarray(ok, jnp.float32),
+            "topk_saturated_rows": jnp.asarray(saturated_rows, jnp.float32)}
+
+
 def _decayed_err(err_prev, pf, err_decay):
     """``where(p, err, err_decay * err)`` — the EF-decay half of
     ``masked_inputs``, factored out so the streaming path (DESIGN.md
@@ -264,6 +278,7 @@ def _sweep2_slice(score_p, tau, off, size, maxpb: int):
     return ck, ci + jnp.uint32(off), jnp.max(cnts) <= maxpb
 
 
+@stages.scope("sweep")
 def _candidates_pallas(kind, g, err_prev, c, step, *, k: int,
                        regtopk: bool, momentum: float, mom, bounds,
                        gate=None, g_segments=None):
@@ -323,6 +338,7 @@ def _candidates_pallas(kind, g, err_prev, c, step, *, k: int,
     return a, mom_out, cand_k, cand_i, producer_ok
 
 
+@stages.scope("sweep")
 def _candidates_xla(kind, g, err_prev, c, *, k: int, momentum: float,
                     mom, bounds, gate=None, g_segments=None):
     """Per-bucket XLA candidate compaction.
@@ -403,20 +419,21 @@ def _fused_randk(g, err_prev, *, k: int, key, want_ghat: bool,
     from repro.core.select import randk_indices
     assert key is not None, "randk needs a PRNG key"
     j = err_prev.shape[0]
-    if g_segments is not None:
-        # streaming: the one elementwise sweep runs per segment (err + g
-        # commutes with the partition bitwise); index sampling is
-        # selection-score-free, so nothing else changes
-        a_parts = [
-            _sweep1_xla("randk", g_segments[pos],
-                        err_prev[off:off + size], jnp.float32(1.0),
-                        momentum=0.0, mom=None)[0]
-            for pos, (off, size) in enumerate(stream_bounds)]
-        a = (a_parts[0] if len(a_parts) == 1
-             else jnp.concatenate(a_parts))
-    else:
-        a, _, _ = _sweep1_xla("randk", g, err_prev, jnp.float32(1.0),
-                              momentum=0.0, mom=None)
+    with stages.scope("sweep"):
+        if g_segments is not None:
+            # streaming: the one elementwise sweep runs per segment (err
+            # + g commutes with the partition bitwise); index sampling is
+            # selection-score-free, so nothing else changes
+            a_parts = [
+                _sweep1_xla("randk", g_segments[pos],
+                            err_prev[off:off + size], jnp.float32(1.0),
+                            momentum=0.0, mom=None)[0]
+                for pos, (off, size) in enumerate(stream_bounds)]
+            a = (a_parts[0] if len(a_parts) == 1
+                 else jnp.concatenate(a_parts))
+        else:
+            a, _, _ = _sweep1_xla("randk", g, err_prev, jnp.float32(1.0),
+                                  momentum=0.0, mom=None)
     if allocation != "global":
         from repro.core import allocate
         bounds = seg_bounds or allocate.segment_bounds(
@@ -429,23 +446,27 @@ def _fused_randk(g, err_prev, *, k: int, key, want_ghat: bool,
     # the O(k) state write runs, so it updates in place
     values = bigvec.gather(a, idx)
     count = jnp.asarray(k, jnp.int32)
-    if pf is None:
-        err = bigvec.scatter_set(a.astype(jnp.dtype(ef_dtype)), idx, 0.0)
-    else:
-        # elastic: a sitting-out worker keeps err = a (= decayed err —
-        # inputs are pre-masked) and ships an inert payload
-        err = bigvec.scatter_set(a.astype(jnp.dtype(ef_dtype)),
-                                 bigvec.live_idx(idx, pf, j), 0.0,
-                                 mode="drop")
-        values = jnp.where(pf, values, 0.0)
-        idx = jnp.where(pf, idx, jnp.zeros_like(idx))
-        count = jnp.where(pf, count, 0)
+    with stages.scope("ef_write"):
+        if pf is None:
+            err = bigvec.scatter_set(a.astype(jnp.dtype(ef_dtype)), idx,
+                                     0.0)
+        else:
+            # elastic: a sitting-out worker keeps err = a (= decayed err
+            # — inputs are pre-masked) and ships an inert payload
+            err = bigvec.scatter_set(a.astype(jnp.dtype(ef_dtype)),
+                                     bigvec.live_idx(idx, pf, j), 0.0,
+                                     mode="drop")
+            values = jnp.where(pf, values, 0.0)
+            idx = jnp.where(pf, idx, jnp.zeros_like(idx))
+            count = jnp.where(pf, count, 0)
     ghat = None
     if want_ghat:
-        ghat = bigvec.scatter_set(jnp.zeros((j,), jnp.float32), idx, values)
+        with stages.scope("exchange"):
+            ghat = bigvec.scatter_set(jnp.zeros((j,), jnp.float32), idx,
+                                      values)
     return {"err": err, "values": values, "indices": idx,
             "ghat": ghat, "mom": None, "count": count,
-            "tau": None}
+            "tau": None, **trim_counters(True, 0)}
 
 
 def fused_sketch_encode(g, err_prev, *, rows: int, width: int,
@@ -494,6 +515,7 @@ def fused_sketch_encode(g, err_prev, *, rows: int, width: int,
     return {"a": a, "sketch": sk}
 
 
+@stages.scope("sweep")
 def _seg_candidates_pallas(kind, g, err_prev, c, step, *, provs, k: int,
                            regtopk: bool, momentum: float, mom, bounds,
                            gate=None, g_segments=None):
@@ -539,6 +561,7 @@ def _seg_candidates_pallas(kind, g, err_prev, c, step, *, provs, k: int,
     return a, mom_out, ck_parts, ci_parts, ok_parts
 
 
+@stages.scope("sweep")
 def _seg_candidates_xla(kind, g, err_prev, c, *, provs, slack, momentum,
                         mom, bounds, gate=None, g_segments=None):
     """Per-SEGMENT XLA candidate compaction for allocation != "global":
@@ -672,15 +695,16 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
     # (allocate.dense_segment_moments over the corrected score).
     skey = None
     if regtopk:
-        skey = _posterior_keys(bigvec.gather(a, idx_prev), a_prev_sel,
-                               g_prev_sel, step, omega=omega, mu=mu)
-        idx_sorted = jnp.sort(idx_prev.astype(jnp.uint32))
-        for pos in range(len(bounds)):
-            ci_l = ci_parts[pos]
-            p = jnp.minimum(jnp.searchsorted(idx_sorted, ci_l),
-                            idx_sorted.shape[0] - 1)
-            hit = (idx_sorted[p] == ci_l) & (step > 0)
-            ck_parts[pos] = jnp.where(hit, -jnp.inf, ck_parts[pos])
+        with stages.scope("support"):
+            skey = _posterior_keys(bigvec.gather(a, idx_prev), a_prev_sel,
+                                   g_prev_sel, step, omega=omega, mu=mu)
+            idx_sorted = jnp.sort(idx_prev.astype(jnp.uint32))
+            for pos in range(len(bounds)):
+                ci_l = ci_parts[pos]
+                p = jnp.minimum(jnp.searchsorted(idx_sorted, ci_l),
+                                idx_sorted.shape[0] - 1)
+                hit = (idx_sorted[p] == ci_l) & (step > 0)
+                ck_parts[pos] = jnp.where(hit, -jnp.inf, ck_parts[pos])
 
     # phase A, per segment: corrected candidate pool, rank the
     # top-trim_cap_l (counts-independent), gather the signed a-values
@@ -688,70 +712,74 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
     # top-cap mass moments from the RANKED CORRECTED keys, which equal
     # allocate.dense_segment_moments bitwise whenever the cover holds
     # (same sorted values, same summation order)
-    seg_trims, ms = [], []
-    for pos, ((off, size), cap) in enumerate(zip(bounds, trim_caps)):
-        allk, alli = ck_parts[pos], ci_parts[pos]
-        if regtopk:
-            in_seg = ((idx_prev >= jnp.uint32(off))
-                      & (idx_prev < jnp.uint32(off + size)))
-            allk = jnp.concatenate([allk,
-                                    jnp.where(in_seg, skey, -jnp.inf)])
-            alli = jnp.concatenate([alli, idx_prev.astype(jnp.uint32)])
-        eff = max(1, int(min(cap, allk.shape[0])))
-        tv, tsel = jax.lax.top_k(allk, eff)
-        allv = bigvec.gather(a, jnp.minimum(alli, jnp.uint32(j - 1)))
-        seg_trims.append((allk, tv, alli[tsel], allv[tsel], eff))
-        if allocation == "adaptive":
-            ms.append(jnp.sum(jnp.where(tv > -jnp.inf, tv * tv, 0.0)))
-            if eff < cap:
-                # ranked pool shorter than the statistic's window: the
-                # top-cap mass cannot be complete — route to fallback
-                ok = ok & jnp.asarray(False)
-    if allocation == "adaptive":
-        counts = allocate.adaptive_counts(k, sizes, jnp.stack(ms),
-                                          caps=caps)
-    else:
-        counts = jnp.asarray(counts_static, jnp.int32)
-
-    # phase B, per segment: leading counts[l] of the ranking are live;
-    # witnesses guard the selection cover AND (adaptive) the statistic's
-    # top-cap cover, so a truncated cover can never silently shift k_l
-    pk_parts, pi_parts, pv_parts = [], [], []
-    for pos, (allk, tv, isel, vsel, eff) in enumerate(seg_trims):
-        kl = counts[pos]
-        has = kl > 0
-        live = jnp.arange(eff, dtype=jnp.int32) < kl
-        kth = tv[jnp.clip(kl - 1, 0, eff - 1)]
-        ok = ok & jnp.where(has, kth > -jnp.inf, True) & (kl <= eff)
-        if wit_parts is not None:
-            full_cover, row_min = wit_parts[pos]
-            tau_l = jnp.where(has, kth, jnp.inf)
+    with stages.scope("trim"):
+        seg_trims, ms = [], []
+        for pos, ((off, size), cap) in enumerate(zip(bounds, trim_caps)):
+            allk, alli = ck_parts[pos], ci_parts[pos]
+            if regtopk:
+                in_seg = ((idx_prev >= jnp.uint32(off))
+                          & (idx_prev < jnp.uint32(off + size)))
+                allk = jnp.concatenate([allk,
+                                        jnp.where(in_seg, skey, -jnp.inf)])
+                alli = jnp.concatenate([alli, idx_prev.astype(jnp.uint32)])
+            eff = max(1, int(min(cap, allk.shape[0])))
+            tv, tsel = jax.lax.top_k(allk, eff)
+            allv = bigvec.gather(a, jnp.minimum(alli, jnp.uint32(j - 1)))
+            seg_trims.append((allk, tv, alli[tsel], allv[tsel], eff))
             if allocation == "adaptive":
-                # stricter: no row may hide an entry that belongs in the
-                # ranked top-eff the moments were summed over
-                tau_l = jnp.minimum(tau_l, tv[eff - 1])
-            ok = ok & (full_cover | (jnp.max(row_min) < tau_l))
-        if regtopk:
-            # boundary tie involving a corrected support key (appended
-            # out of index order): same ambiguity rule as the global
-            # exact trim, per segment
-            n_gt = jnp.sum((allk > kth).astype(jnp.int32))
-            n_eq = jnp.sum((allk == kth).astype(jnp.int32))
-            support_tie = jnp.any(allk[-idx_prev.shape[0]:] == kth)
-            ok = ok & jnp.where(has, (n_eq == (kl - n_gt)) | ~support_tie,
-                                True)
-        pk_parts.append(jnp.where(live, tv, -jnp.inf))
-        pi_parts.append(isel)
-        pv_parts.append(vsel)
-    # pack: one O(sum(caps)) top-k over the live-masked union -> exactly
-    # the sum(k_l) == k live entries, ordered by key desc (ties resolve
-    # segment-major then index asc — allocated_select_dense's order)
-    packk = jnp.concatenate(pk_parts)
-    packi = jnp.concatenate(pi_parts)
-    packv = jnp.concatenate(pv_parts)
-    _tvg, sel = jax.lax.top_k(packk, k)
-    idx_fast = packi[sel]
-    val_fast = packv[sel]
+                ms.append(jnp.sum(jnp.where(tv > -jnp.inf, tv * tv, 0.0)))
+                if eff < cap:
+                    # ranked pool shorter than the statistic's window: the
+                    # top-cap mass cannot be complete — route to fallback
+                    ok = ok & jnp.asarray(False)
+        if allocation == "adaptive":
+            counts = allocate.adaptive_counts(k, sizes, jnp.stack(ms),
+                                              caps=caps)
+        else:
+            counts = jnp.asarray(counts_static, jnp.int32)
+
+        # phase B, per segment: leading counts[l] of the ranking are live;
+        # witnesses guard the selection cover AND (adaptive) the statistic's
+        # top-cap cover, so a truncated cover can never silently shift k_l
+        pk_parts, pi_parts, pv_parts = [], [], []
+        saturated = 0
+        for pos, (allk, tv, isel, vsel, eff) in enumerate(seg_trims):
+            kl = counts[pos]
+            has = kl > 0
+            live = jnp.arange(eff, dtype=jnp.int32) < kl
+            kth = tv[jnp.clip(kl - 1, 0, eff - 1)]
+            ok = ok & jnp.where(has, kth > -jnp.inf, True) & (kl <= eff)
+            if wit_parts is not None:
+                full_cover, row_min = wit_parts[pos]
+                tau_l = jnp.where(has, kth, jnp.inf)
+                if allocation == "adaptive":
+                    # stricter: no row may hide an entry that belongs in the
+                    # ranked top-eff the moments were summed over
+                    tau_l = jnp.minimum(tau_l, tv[eff - 1])
+                ok = ok & (full_cover | (jnp.max(row_min) < tau_l))
+                if not full_cover:
+                    saturated = saturated + jnp.sum(row_min >= tau_l)
+            if regtopk:
+                # boundary tie involving a corrected support key (appended
+                # out of index order): same ambiguity rule as the global
+                # exact trim, per segment
+                n_gt = jnp.sum((allk > kth).astype(jnp.int32))
+                n_eq = jnp.sum((allk == kth).astype(jnp.int32))
+                support_tie = jnp.any(allk[-idx_prev.shape[0]:] == kth)
+                ok = ok & jnp.where(has, (n_eq == (kl - n_gt)) | ~support_tie,
+                                    True)
+            pk_parts.append(jnp.where(live, tv, -jnp.inf))
+            pi_parts.append(isel)
+            pv_parts.append(vsel)
+        # pack: one O(sum(caps)) top-k over the live-masked union -> exactly
+        # the sum(k_l) == k live entries, ordered by key desc (ties resolve
+        # segment-major then index asc — allocated_select_dense's order)
+        packk = jnp.concatenate(pk_parts)
+        packi = jnp.concatenate(pi_parts)
+        packv = jnp.concatenate(pv_parts)
+        _tvg, sel = jax.lax.top_k(packk, k)
+        idx_fast = packi[sel]
+        val_fast = packv[sel]
 
     def _flat_g():
         # fallback-only: materialize the flat (effective) gradient — on
@@ -773,6 +801,7 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
     def _fast(_):
         return idx_fast, val_fast
 
+    @stages.scope("fallback")
     def _fallback(_):
         a2, score2, _ = _sweep1_xla(kind, _flat_g(), err_prev, c,
                                     momentum=momentum, mom=mom, gate=gate)
@@ -796,7 +825,8 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
                                                      counts_d, k)
         return idx_d, _gather_inputs(idx_d)
 
-    idx_k, values = jax.lax.cond(ok, _fast, _fallback, operand=None)
+    with stages.scope("trim"):
+        idx_k, values = jax.lax.cond(ok, _fast, _fallback, operand=None)
     # O(k) state tail, identical to the global exact path; under elastic
     # participation a sitting-out worker skips the scatter-zero (sentinel
     # + drop) so err/mom keep their decayed values, and the packed
@@ -809,18 +839,20 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
         idx_k = jnp.where(pf, idx_k, jnp.zeros_like(idx_k))
         count = jnp.where(pf, count, 0)
     dt = jnp.dtype(ef_dtype)
-    err = bigvec.scatter_set(a.astype(dt), idx_w, 0.0, mode="drop")
-    if kind == "dgc":
-        mom_out = bigvec.scatter_set(mom_out.astype(dt), idx_w, 0.0,
-                                     mode="drop")
+    with stages.scope("ef_write"):
+        err = bigvec.scatter_set(a.astype(dt), idx_w, 0.0, mode="drop")
+        if kind == "dgc":
+            mom_out = bigvec.scatter_set(mom_out.astype(dt), idx_w, 0.0,
+                                         mode="drop")
     ghat = None
     if want_ghat:
-        ghat = bigvec.scatter_set(jnp.zeros((j,), jnp.float32),
-                                  idx_k, values)
+        with stages.scope("exchange"):
+            ghat = bigvec.scatter_set(jnp.zeros((j,), jnp.float32),
+                                      idx_k, values)
     return {"err": err, "values": values,
             "indices": idx_k.astype(jnp.uint32), "ghat": ghat,
             "mom": mom_out, "count": count,
-            "tau": None}
+            "tau": None, **trim_counters(ok, saturated)}
 
 
 def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
@@ -1001,73 +1033,80 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
 
     support_valid = None
     if regtopk:
-        if nsel_prev is not None:
-            support_valid = (jnp.arange(idx_prev.shape[0], dtype=jnp.int32)
-                             < nsel_prev)
-        skey = _posterior_keys(bigvec.gather(a, idx_prev), a_prev_sel,
-                               g_prev_sel, step, omega=omega, mu=mu,
-                               support_valid=support_valid)
-        # candidates that are support members carry an uncorrected key:
-        # disable them (the corrected copy is appended below). With no
-        # dense mask in the state, membership is resolved against the
-        # O(k) posterior support itself — sort + searchsorted in
-        # candidate space, O((k + cand) log k), no O(J) array touched.
-        if support_valid is not None:
-            # inert pad slots alias index 0: exclude them via the
-            # out-of-range sentinel before the sort (bigvec.live_idx)
-            idx_live = bigvec.live_idx(idx_prev, support_valid, j)
-        else:
-            idx_live = idx_prev.astype(jnp.uint32)
-        idx_sorted = jnp.sort(idx_live)
-        pos = jnp.minimum(jnp.searchsorted(idx_sorted, cand_i),
-                          idx_sorted.shape[0] - 1)
-        hit = (idx_sorted[pos] == cand_i) & (step > 0)
-        cand_k = jnp.where(hit, -jnp.inf, cand_k)
-        allk = jnp.concatenate([cand_k, skey])
-        alli = jnp.concatenate([cand_i, idx_prev.astype(jnp.uint32)])
+        with stages.scope("support"):
+            if nsel_prev is not None:
+                support_valid = (jnp.arange(idx_prev.shape[0],
+                                            dtype=jnp.int32) < nsel_prev)
+            skey = _posterior_keys(bigvec.gather(a, idx_prev), a_prev_sel,
+                                   g_prev_sel, step, omega=omega, mu=mu,
+                                   support_valid=support_valid)
+            # candidates that are support members carry an uncorrected
+            # key: disable them (the corrected copy is appended below).
+            # With no dense mask in the state, membership is resolved
+            # against the O(k) posterior support itself — sort +
+            # searchsorted in candidate space, O((k + cand) log k), no
+            # O(J) array touched.
+            if support_valid is not None:
+                # inert pad slots alias index 0: exclude them via the
+                # out-of-range sentinel before the sort (bigvec.live_idx)
+                idx_live = bigvec.live_idx(idx_prev, support_valid, j)
+            else:
+                idx_live = idx_prev.astype(jnp.uint32)
+            idx_sorted = jnp.sort(idx_live)
+            pos = jnp.minimum(jnp.searchsorted(idx_sorted, cand_i),
+                              idx_sorted.shape[0] - 1)
+            hit = (idx_sorted[pos] == cand_i) & (step > 0)
+            cand_k = jnp.where(hit, -jnp.inf, cand_k)
+            allk = jnp.concatenate([cand_k, skey])
+            alli = jnp.concatenate([cand_i, idx_prev.astype(jnp.uint32)])
     else:
         allk, alli = cand_k, cand_i
 
-    tv, tsel = jax.lax.top_k(allk, kcap)
-    idx_fast = alli[tsel]
-    # signed a-values of every trim entry, gathered from the dense ``a``
-    # BEFORE the cond: every read of a's buffer stays ahead of the final
-    # err scatter-zero, which can then update it in place (a post-cond
-    # gather would extend a's liveness and cost a defensive O(J) copy).
-    # Clamp: Pallas INVALID_IDX slots carry -inf keys and are never
-    # selected on the fast path.
-    allv = bigvec.gather(a, jnp.minimum(alli, jnp.uint32(j - 1)))
-    val_fast = allv[tsel]
-    kth = tv[k - 1]
-    valid = kth > -jnp.inf
-    # histogram tau: bit-pattern bin lower edge of the k-th key. The
-    # sweep-2 compaction threshold (merged-histogram tau at target
-    # kcap + margin) is <= this edge, so the candidates cover every
-    # entry >= tau (kernel.key_bin_edge docstring).
-    tau = pk.key_bin_edge(kth) if hist else kth
-    if producer_ok is None:                  # xla strategy witness
-        # a bucket can hide a missed entry only if one of its rows
-        # saturated its W candidate slots at or above the selection
-        # threshold (the global tau)
-        producer_ok = valid
-        for full_cover, row_min in witnesses:
-            ok_b = full_cover | (jnp.max(row_min) < tau)
-            producer_ok = jnp.logical_and(producer_ok, ok_b)
-    ok = producer_ok & valid
-    if regtopk and not hist:
-        # Boundary ties among compacted candidates resolve exactly like the
-        # reference (candidate position order == global index order). The
-        # one exception: a tie involving a corrected SUPPORT key (appended
-        # last, out of index order) with more ties than slots — fallback.
-        # (Histogram selection has no exact-parity contract: every tie at
-        # tau is either wholly selected or cut at the fixed capacity.)
-        n_gt = jnp.sum((allk > kth).astype(jnp.int32))
-        n_eq = jnp.sum((allk == kth).astype(jnp.int32))
-        support_tie = jnp.any(skey == kth)
-        ok = ok & ((n_eq == (k - n_gt)) | ~support_tie)
+    with stages.scope("trim"):
+        tv, tsel = jax.lax.top_k(allk, kcap)
+        idx_fast = alli[tsel]
+        # signed a-values of every trim entry, gathered from the dense
+        # ``a`` BEFORE the cond: every read of a's buffer stays ahead of
+        # the final err scatter-zero, which can then update it in place
+        # (a post-cond gather would extend a's liveness and cost a
+        # defensive O(J) copy). Clamp: Pallas INVALID_IDX slots carry
+        # -inf keys and are never selected on the fast path.
+        allv = bigvec.gather(a, jnp.minimum(alli, jnp.uint32(j - 1)))
+        val_fast = allv[tsel]
+        kth = tv[k - 1]
+        valid = kth > -jnp.inf
+        # histogram tau: bit-pattern bin lower edge of the k-th key. The
+        # sweep-2 compaction threshold (merged-histogram tau at target
+        # kcap + margin) is <= this edge, so the candidates cover every
+        # entry >= tau (kernel.key_bin_edge docstring).
+        tau = pk.key_bin_edge(kth) if hist else kth
+        saturated = 0
+        if producer_ok is None:                  # xla strategy witness
+            # a bucket can hide a missed entry only if one of its rows
+            # saturated its W candidate slots at or above the selection
+            # threshold (the global tau)
+            producer_ok = valid
+            for full_cover, row_min in witnesses:
+                ok_b = full_cover | (jnp.max(row_min) < tau)
+                producer_ok = jnp.logical_and(producer_ok, ok_b)
+                if not full_cover:
+                    saturated = saturated + jnp.sum(row_min >= tau)
+        ok = producer_ok & valid
+        if regtopk and not hist:
+            # Boundary ties among compacted candidates resolve exactly
+            # like the reference (candidate position order == global
+            # index order). The one exception: a tie involving a
+            # corrected SUPPORT key (appended last, out of index order)
+            # with more ties than slots — fallback. (Histogram selection
+            # has no exact-parity contract: every tie at tau is either
+            # wholly selected or cut at the fixed capacity.)
+            n_gt = jnp.sum((allk > kth).astype(jnp.int32))
+            n_eq = jnp.sum((allk == kth).astype(jnp.int32))
+            support_tie = jnp.any(skey == kth)
+            ok = ok & ((n_eq == (k - n_gt)) | ~support_tie)
 
     def _fallback_keys():
-        # adversarial-input escape hatch: recompute (a, keys) from the
+        # the fallback's keys: recompute (a, keys) from the
         # *function parameters* rather than capturing the intermediate
         # `a` — XLA CPU copies non-parameter conditional operands, which
         # would tax the fast path with an O(J) copy. The elastic masking
@@ -1097,6 +1136,7 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
         def _fast(_):
             return idx_fast, val_fast, tv >= tau, tau
 
+        @stages.scope("fallback")
         def _fallback(_):
             keys_d = _fallback_keys()
             from repro.core import select
@@ -1105,35 +1145,41 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
             tau_d = pk.key_bin_edge(tvd[k - 1])
             return idx_d, _gather_inputs(idx_d), tvd >= tau_d, tau_d
 
-        idx_k, vraw, valid_sel, tau = jax.lax.cond(ok, _fast, _fallback,
-                                                   operand=None)
-        if pf is not None:
-            # elastic: a sitting-out worker's payload is wholly inert —
-            # masking valid_sel itself routes the state scatters to the
-            # sentinel (err keeps its decayed value) AND zeroes
-            # values/indices/count through the pad-slot handling below
-            valid_sel = valid_sel & pf
-        values = jnp.where(valid_sel, vraw, 0.0)
-        idx_k = jnp.where(valid_sel, idx_k, 0).astype(jnp.uint32)
-        count = jnp.sum(valid_sel.astype(jnp.int32))
-        # inert pad slots must never zero a live entry's error feedback:
-        # sentinel + drop for the O(k) state scatters (bigvec.live_idx)
-        idx_w = bigvec.live_idx(idx_k, valid_sel, j)
+        with stages.scope("trim"):
+            idx_k, vraw, valid_sel, tau = jax.lax.cond(ok, _fast, _fallback,
+                                                       operand=None)
+            if pf is not None:
+                # elastic: a sitting-out worker's payload is wholly inert
+                # — masking valid_sel itself routes the state scatters to
+                # the sentinel (err keeps its decayed value) AND zeroes
+                # values/indices/count through the pad-slot handling below
+                valid_sel = valid_sel & pf
+            values = jnp.where(valid_sel, vraw, 0.0)
+            idx_k = jnp.where(valid_sel, idx_k, 0).astype(jnp.uint32)
+            count = jnp.sum(valid_sel.astype(jnp.int32))
+            # inert pad slots must never zero a live entry's error
+            # feedback: sentinel + drop for the O(k) state scatters
+            # (bigvec.live_idx)
+            idx_w = bigvec.live_idx(idx_k, valid_sel, j)
         ghat = None
         if want_ghat:
             # scatter-ADD: a pad's (0, 0.0) never clobbers index 0
-            ghat = bigvec.scatter_add(jnp.zeros((j,), jnp.float32),
-                                      idx_k, values)
+            with stages.scope("exchange"):
+                ghat = bigvec.scatter_add(jnp.zeros((j,), jnp.float32),
+                                          idx_k, values)
     else:
         def _fast(_):
             return idx_fast, val_fast
 
+        @stages.scope("fallback")
         def _fallback(_):
             from repro.core import select
             idx_d = select.topk_indices(_fallback_keys(), k)
             return idx_d, _gather_inputs(idx_d)
 
-        idx_k, values = jax.lax.cond(ok, _fast, _fallback, operand=None)
+        with stages.scope("trim"):
+            idx_k, values = jax.lax.cond(ok, _fast, _fallback,
+                                         operand=None)
         count = jnp.asarray(k, jnp.int32)
         tau = None
         idx_w = idx_k                        # exact: all k slots live
@@ -1146,8 +1192,9 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
             count = jnp.where(pf, count, 0)
         ghat = None
         if want_ghat:
-            ghat = bigvec.scatter_set(jnp.zeros((j,), jnp.float32),
-                                      idx_k, values)
+            with stages.scope("exchange"):
+                ghat = bigvec.scatter_set(jnp.zeros((j,), jnp.float32),
+                                          idx_k, values)
     # --- O(k) state writes ---------------------------------------------
     # err^{t+1} = a * (1 - s): zero the selected slots of a in place —
     # the ONLY J-sized state, written by an O(k) scatter (the third
@@ -1155,11 +1202,13 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
     # ef_dtype cast happens BEFORE the scatter so bf16 state fuses into
     # the sweep-1 stream instead of adding a post-scatter convert pass.
     dt = jnp.dtype(ef_dtype)
-    err = bigvec.scatter_set(a.astype(dt), idx_w, 0.0, mode="drop")
-    if kind == "dgc":
-        # momentum masking mom * (1 - s), same O(k) scatter-zero
-        mom_out = bigvec.scatter_set(mom_out.astype(dt), idx_w, 0.0,
-                                     mode="drop")
+    with stages.scope("ef_write"):
+        err = bigvec.scatter_set(a.astype(dt), idx_w, 0.0, mode="drop")
+        if kind == "dgc":
+            # momentum masking mom * (1 - s), same O(k) scatter-zero
+            mom_out = bigvec.scatter_set(mom_out.astype(dt), idx_w, 0.0,
+                                         mode="drop")
     return {"err": err, "values": values,
             "indices": idx_k.astype(jnp.uint32), "ghat": ghat,
-            "mom": mom_out, "count": count, "tau": tau}
+            "mom": mom_out, "count": count, "tau": tau,
+            **trim_counters(ok, saturated)}

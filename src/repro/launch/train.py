@@ -313,6 +313,8 @@ def main(argv=None):
                 print(f"step {t:5d} loss {m['loss']:.4f} "
                       f"gnorm {m['gnorm_local']:.3f} "
                       f"nz {m['agg_nonzero']:.4f} "
+                      f"fb {m['topk_fallback']:.2f} "
+                      f"sat {m['topk_saturated_rows']:.0f} "
                       f"{health}({time.time()-t0:.1f}s)")
             if publisher is not None and (t + 1) % max(
                     1, args.delta_every) == 0:

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import RunConfig
 from repro.core import aggregate as agg
 from repro.core import sparsify
+from repro.core import stages
 from repro.core.flatten import TreeFlattener
 from repro.models import init_params, loss_fn
 from repro.models.parallel import Parallel
@@ -76,9 +77,10 @@ def _dp_index(dpaxes):
 
 
 def _gather_dp(x, dpaxes):
-    for a in reversed(dpaxes):
-        x = jax.lax.all_gather(x, a, axis=0, tiled=True)
-    return x
+    with stages.scope("master_gather"):
+        for a in reversed(dpaxes):
+            x = jax.lax.all_gather(x, a, axis=0, tiled=True)
+        return x
 
 
 # ---------------------------------------------------------------------------
@@ -278,7 +280,8 @@ def build_train_step(run: RunConfig, mesh, pal: Parallel):
         ef_state = sq(ef_state)
 
         def loss_f(p):
-            return loss_fn(p, batch, cfg, pal, window=window)
+            with stages.scope("fwd"):
+                return loss_fn(p, batch, cfg, pal, window=window)
 
         (loss, aux), grads = jax.value_and_grad(loss_f, has_aux=True)(params)
         if pal.tp_on:
@@ -289,16 +292,17 @@ def build_train_step(run: RunConfig, mesh, pal: Parallel):
             # own leaves' gradients — compression runs behind the
             # remaining backward work (DESIGN.md §2.8)
             g_segments = flat.flatten_segments(grads, stream_bounds)
-            gnorm_local = jnp.sqrt(sum(
-                jnp.sum(jnp.square(s.astype(jnp.float32)))
-                for s in g_segments))
+            with stages.scope("step_metrics"):
+                gnorm_local = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(s.astype(jnp.float32)))
+                    for s in g_segments))
         else:
             g_segments = None
             g = flat.flatten(grads)
-            gnorm_local = jnp.linalg.norm(g)
+            with stages.scope("step_metrics"):
+                gnorm_local = jnp.linalg.norm(g)
 
         key = jax.random.fold_in(key, _dp_index(dpaxes))
-        fstats = None
         part = None
         if sched is not None:
             part = faults.participates(sched, ef_state["step"],
@@ -307,40 +311,43 @@ def build_train_step(run: RunConfig, mesh, pal: Parallel):
             stream = gsync.begin(ef_state, key=key, participate=part)
             for gseg in g_segments:
                 stream.feed_segment(gseg)
-            if sched is None:
-                g_agg, ef_new = stream.finish()
-            else:
-                g_agg, ef_new, fstats = stream.finish(with_stats=True)
-        elif sched is None:
-            g_agg, ef_new = gsync(ef_state, g, key=key)
+            g_agg, ef_new, fstats = stream.finish(with_stats=True)
         else:
             g_agg, ef_new, fstats = gsync(ef_state, g, key=key,
                                           participate=part, with_stats=True)
 
         # ZeRO-1 slice update
-        r = _dp_index(dpaxes)
-        gpad = jnp.pad(g_agg.astype(jnp.float32), (0, dp * shard - flat.total))
-        gs = jax.lax.dynamic_slice_in_dim(gpad, r * shard, shard)
-        if opt.grad_clip:
-            w = dup if dup is not None else 1.0
-            gn2 = jnp.sum(g_agg.astype(jnp.float32) ** 2 * w)
-            gn2 = jax.lax.psum(gn2, "model") if pal.tp_on else gn2
-            opt_state = dict(opt_state, gnorm=jnp.sqrt(gn2))
-        master, opt_new = apply_updates(opt, opt_state, gs)
+        with stages.scope("adam"):
+            r = _dp_index(dpaxes)
+            gpad = jnp.pad(g_agg.astype(jnp.float32),
+                           (0, dp * shard - flat.total))
+            gs = jax.lax.dynamic_slice_in_dim(gpad, r * shard, shard)
+            if opt.grad_clip:
+                w = dup if dup is not None else 1.0
+                gn2 = jnp.sum(g_agg.astype(jnp.float32) ** 2 * w)
+                gn2 = jax.lax.psum(gn2, "model") if pal.tp_on else gn2
+                opt_state = dict(opt_state, gnorm=jnp.sqrt(gn2))
+            master, opt_new = apply_updates(opt, opt_state, gs)
         mall = _gather_dp(master, dpaxes)[:flat.total]
         params_new = flat.unflatten(mall)
 
         from repro.models.transformer import global_loss
-        metrics = {
-            "loss": global_loss(loss, pal),          # psum over model first
-            "gnorm_local": gnorm_local,
-            "agg_nonzero": jnp.mean((g_agg != 0).astype(jnp.float32)),
-        }
-        metrics.update(aux)
-        all_axes = dpaxes + (("model",) if pal.tp_on else ())
-        metrics = {k_: jax.lax.pmean(v, dpaxes if k_ == "loss" else all_axes)
-                   for k_, v in metrics.items()}
-        if fstats is not None:
+        with stages.scope("step_metrics"):
+            # topk_fallback / topk_saturated_rows: the trim counters,
+            # already averaged over the data ranks by GradientSync
+            metrics = {
+                "loss": global_loss(loss, pal),      # psum over model first
+                "gnorm_local": gnorm_local,
+                "agg_nonzero": jnp.mean((g_agg != 0).astype(jnp.float32)),
+                "topk_fallback": fstats["topk_fallback"],
+                "topk_saturated_rows": fstats["topk_saturated_rows"],
+            }
+            metrics.update(aux)
+            all_axes = dpaxes + (("model",) if pal.tp_on else ())
+            metrics = {k_: jax.lax.pmean(
+                v, dpaxes if k_ == "loss" else all_axes)
+                for k_, v in metrics.items()}
+        if sched is not None:
             # already rank-identical psums from GradientSync — no pmean
             metrics["n_active"] = fstats["n_active"]
             metrics["dropped_nonfinite"] = fstats["dropped_nonfinite"]
@@ -351,8 +358,8 @@ def build_train_step(run: RunConfig, mesh, pal: Parallel):
         batch_specs["patches"] = P(dpaxes, None, None)
     elif cfg.frontend == "audio_stub":
         batch_specs["frames"] = P(dpaxes, None, None)
-    mkeys = ["loss", "gnorm_local", "agg_nonzero",
-             "lb_loss", "z_loss", "drop_frac"]
+    mkeys = ["loss", "gnorm_local", "agg_nonzero", "topk_fallback",
+             "topk_saturated_rows", "lb_loss", "z_loss", "drop_frac"]
     if sched is not None:
         mkeys += ["n_active", "dropped_nonfinite"]
     mspecs = {k: P() for k in mkeys}

@@ -194,7 +194,9 @@ class TestStreamingElastic:
                     out_specs=(P("data"),
                                jax.tree_util.tree_map(lambda _: P(), st),
                                {"n_active": P(),
-                                "dropped_nonfinite": P()}),
+                                "dropped_nonfinite": P(),
+                                "topk_fallback": P(),
+                                "topk_saturated_rows": P()}),
                     check_vma=False))
                 return fn(g, dict(st))
 
