@@ -33,10 +33,12 @@ Execution strategies (``ops.resolve_strategy``):
   refuses them, so this raises ``ops.PALLAS_TPU_REFUSAL`` before
   tracing.
 
-Both strategies verify exactness (per-block overflow + boundary-tie
-ambiguity) and fall back to a full ``lax.top_k`` under ``lax.cond`` on
-the rare adversarial inputs where the compacted candidate set cannot be
-proven to cover the true top-k.
+Both strategies verify exactness (the per-row cover witness or
+per-block overflow) and fall back to a full ``lax.top_k`` under
+``lax.cond`` on the rare adversarial inputs where the compacted
+candidate set cannot be proven to cover the true top-k. The trims rank
+candidates by (key desc, index asc) explicitly, so ties need no
+fallback.
 
 Density allocation (DESIGN.md §2.6, ``core/allocate.py``): with
 ``SparsifierConfig.allocation`` in {"proportional", "adaptive"} the

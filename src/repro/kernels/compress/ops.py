@@ -55,11 +55,13 @@ kernels) raises before tracing, because the TPU compiler refuses them
 (``PALLAS_TPU_REFUSAL``).
 
 Exactness: the compacted candidate set provably covers the true top-k
-unless the per-row/per-block witnesses say otherwise (or a boundary tie
-is ambiguous under REGTOP-k support corrections); those cases take a
+unless the per-row/per-block witnesses say otherwise; those cases take a
 ``lax.cond`` fallback to a full ``lax.top_k`` with identical semantics.
-Fast path and fallback both reproduce the reference selector's tie-break
-support exactly. Every score-based path returns two counters of its trim
+The trims rank candidates by (key descending, index ascending)
+explicitly (``_rank``), the reference ``lax.top_k``'s order over the
+dense keys, so fast path and fallback both reproduce the reference
+selector's tie-break support and packed order exactly, whatever the
+candidates' positions. Every score-based path returns two counters of its trim
 (``trim_counters``): ``topk_fallback`` (1.0 when the fallback ran) and
 ``topk_saturated_rows`` (on the ``xla`` strategy, the candidate rows
 whose W-th key reached the selection threshold: the cover witness's
@@ -190,6 +192,21 @@ def trim_counters(ok, saturated_rows) -> dict:
     witness (W-th key at or above the selection threshold)."""
     return {"topk_fallback": 1.0 - jnp.asarray(ok, jnp.float32),
             "topk_saturated_rows": jnp.asarray(saturated_rows, jnp.float32)}
+
+
+def _rank(keys, idx, n: int):
+    """The leading ``n`` of (keys, idx) in the reference order: key
+    descending, global index ascending among equal keys — what
+    ``lax.top_k`` over the dense keys gives. Candidate positions do not
+    follow the index (the XLA sweep's rows interleave, and REGTOP-k
+    appends the corrected support keys), so the index is the second key
+    of one O(cand log cand) sort: ascending by (key, ~index), read
+    backwards, which also puts NaN keys first as ``lax.top_k`` does.
+    Finite keys never share an index, so the order of the finite entries
+    is total and the sort need not be stable. Returns (keys (n,), idx
+    (n,))."""
+    sk, si = jax.lax.sort((keys, ~idx), num_keys=2, is_stable=False)
+    return sk[::-1][:n], ~si[::-1][:n]
 
 
 def _decayed_err(err_prev, pf, err_decay):
@@ -345,11 +362,13 @@ def _candidates_xla(kind, g, err_prev, c, *, k: int, momentum: float,
 
     Sweep 1 is one fused elementwise pass over the whole vector (XLA
     fuses across bucket slices anyway); sweep 2's per-row top-W
-    compaction runs per bucket so each bucket's candidate chain is
-    independent. Returns per-bucket (full_cover, row_min) witnesses —
-    the exactness check needs the global tau_k, known only after the
-    trim. Candidate order stays global-index-ascending across buckets,
-    preserving the flat path's tie-break semantics bit-for-bit.
+    compaction runs per bucket, its rows interleaved within the bucket,
+    so each bucket's candidate chain is independent. Returns per-bucket
+    (full_cover, row_min) witnesses — the exactness check needs the
+    global tau_k, known only after the trim. Candidate positions do not
+    follow the global index (interleaved rows); the trim's explicit
+    (key, index) ranking (``_rank``) keeps the flat path's tie-break
+    semantics bit-for-bit.
 
     ``g_segments`` (streaming, DESIGN.md §2.8): sweep 1 runs per
     segment over the standalone segment arrays instead, so the WHOLE
@@ -386,12 +405,11 @@ def _candidates_xla(kind, g, err_prev, c, *, k: int, momentum: float,
         mom_out = None
     ck_parts, ci_parts, witnesses = [], [], []
     for (off, size), key_s in zip(bounds, key_parts):
-        kb = px.pad_keys(key_s)
         # density over the GLOBAL j: a bucket's rows are provisioned
         # exactly like the flat path's (witness + fallback cover
         # concentration), so bucketing adds no candidate-slot cost
         cv, ci, row_min, full_cover = px.candidates_xla(
-            kb, k, density_len=(j if len(bounds) > 1 else 0))
+            key_s, k, density_len=(j if len(bounds) > 1 else 0))
         ck_parts.append(cv)
         ci_parts.append(ci + jnp.uint32(off))
         witnesses.append((full_cover, row_min))
@@ -604,9 +622,8 @@ def _seg_candidates_xla(kind, g, err_prev, c, *, provs, slack, momentum,
         mom_out = None
     ck_parts, ci_parts, wit_parts = [], [], []
     for pos, (off, size) in enumerate(bounds):
-        kb = px.pad_keys(key_parts[pos])
-        cv, ci, row_min, full_cover = px.candidates_xla(kb, provs[pos],
-                                                        slack=slack)
+        cv, ci, row_min, full_cover = px.candidates_xla(
+            key_parts[pos], provs[pos], slack=slack)
         ck_parts.append(cv)
         ci_parts.append(ci + jnp.uint32(off))
         wit_parts.append((full_cover, row_min))
@@ -633,8 +650,8 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
     O(segments * cap log cap), no extra O(J) traversal — audit-gated at
     2.0 sweeps). Exactness witnesses are per segment (coverage vs the
     segment's own realized threshold — and, for adaptive, vs the ranked
-    top-cap the statistics were summed over — REGTOP-k boundary-tie
-    ambiguity, candidate-capacity overflow); any failure takes the
+    top-cap the statistics were summed over — and candidate-capacity
+    overflow; ties rank by index explicitly); any failure takes the
     lax.cond fallback to dense per-segment selection with identical
     semantics, INCLUDING densely recomputed adaptive counts — the
     fallback branch IS the reference pipeline's allocated selector
@@ -723,9 +740,9 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
                                         jnp.where(in_seg, skey, -jnp.inf)])
                 alli = jnp.concatenate([alli, idx_prev.astype(jnp.uint32)])
             eff = max(1, int(min(cap, allk.shape[0])))
-            tv, tsel = jax.lax.top_k(allk, eff)
-            allv = bigvec.gather(a, jnp.minimum(alli, jnp.uint32(j - 1)))
-            seg_trims.append((allk, tv, alli[tsel], allv[tsel], eff))
+            tv, isel = _rank(allk, alli, eff)
+            vsel = bigvec.gather(a, jnp.minimum(isel, jnp.uint32(j - 1)))
+            seg_trims.append((tv, isel, vsel, eff))
             if allocation == "adaptive":
                 ms.append(jnp.sum(jnp.where(tv > -jnp.inf, tv * tv, 0.0)))
                 if eff < cap:
@@ -743,7 +760,7 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
         # top-cap cover, so a truncated cover can never silently shift k_l
         pk_parts, pi_parts, pv_parts = [], [], []
         saturated = 0
-        for pos, (allk, tv, isel, vsel, eff) in enumerate(seg_trims):
+        for pos, (tv, isel, vsel, eff) in enumerate(seg_trims):
             kl = counts[pos]
             has = kl > 0
             live = jnp.arange(eff, dtype=jnp.int32) < kl
@@ -759,21 +776,13 @@ def _fused_allocated(kind, g, err_prev, step, *, k: int, omega, mu, Q,
                 ok = ok & (full_cover | (jnp.max(row_min) < tau_l))
                 if not full_cover:
                     saturated = saturated + jnp.sum(row_min >= tau_l)
-            if regtopk:
-                # boundary tie involving a corrected support key (appended
-                # out of index order): same ambiguity rule as the global
-                # exact trim, per segment
-                n_gt = jnp.sum((allk > kth).astype(jnp.int32))
-                n_eq = jnp.sum((allk == kth).astype(jnp.int32))
-                support_tie = jnp.any(allk[-idx_prev.shape[0]:] == kth)
-                ok = ok & jnp.where(has, (n_eq == (kl - n_gt)) | ~support_tie,
-                                    True)
             pk_parts.append(jnp.where(live, tv, -jnp.inf))
             pi_parts.append(isel)
             pv_parts.append(vsel)
         # pack: one O(sum(caps)) top-k over the live-masked union -> exactly
         # the sum(k_l) == k live entries, ordered by key desc (ties resolve
-        # segment-major then index asc — allocated_select_dense's order)
+        # segment-major then index asc — allocated_select_dense's order;
+        # each segment's ranking is already index-ascending among ties)
         packk = jnp.concatenate(pk_parts)
         packi = jnp.concatenate(pi_parts)
         packv = jnp.concatenate(pv_parts)
@@ -1063,16 +1072,14 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
         allk, alli = cand_k, cand_i
 
     with stages.scope("trim"):
-        tv, tsel = jax.lax.top_k(allk, kcap)
-        idx_fast = alli[tsel]
-        # signed a-values of every trim entry, gathered from the dense
+        tv, idx_fast = _rank(allk, alli, kcap)
+        # signed a-values of the ranked entries, gathered from the dense
         # ``a`` BEFORE the cond: every read of a's buffer stays ahead of
         # the final err scatter-zero, which can then update it in place
         # (a post-cond gather would extend a's liveness and cost a
         # defensive O(J) copy). Clamp: Pallas INVALID_IDX slots carry
         # -inf keys and are never selected on the fast path.
-        allv = bigvec.gather(a, jnp.minimum(alli, jnp.uint32(j - 1)))
-        val_fast = allv[tsel]
+        val_fast = bigvec.gather(a, jnp.minimum(idx_fast, jnp.uint32(j - 1)))
         kth = tv[k - 1]
         valid = kth > -jnp.inf
         # histogram tau: bit-pattern bin lower edge of the k-th key. The
@@ -1092,18 +1099,6 @@ def fused_compress_arrays(kind: str, g, err_prev, step, *, k: int,
                 if not full_cover:
                     saturated = saturated + jnp.sum(row_min >= tau)
         ok = producer_ok & valid
-        if regtopk and not hist:
-            # Boundary ties among compacted candidates resolve exactly
-            # like the reference (candidate position order == global
-            # index order). The one exception: a tie involving a
-            # corrected SUPPORT key (appended last, out of index order)
-            # with more ties than slots — fallback. (Histogram selection
-            # has no exact-parity contract: every tie at tau is either
-            # wholly selected or cut at the fixed capacity.)
-            n_gt = jnp.sum((allk > kth).astype(jnp.int32))
-            n_eq = jnp.sum((allk == kth).astype(jnp.int32))
-            support_tie = jnp.any(skey == kth)
-            ok = ok & ((n_eq == (k - n_gt)) | ~support_tie)
 
     def _fallback_keys():
         # the fallback's keys: recompute (a, keys) from the

@@ -103,13 +103,15 @@ K = 32
 
 def _gradient(spread: bool):
     """Small distinct background keys plus large entries: K distinct ones
-    spread evenly over the four 8,192-entry rows, or 512 equal ones in
-    row 0, more than any row's W candidate slots here."""
+    spread evenly over the four interleaved 8,192-entry candidate rows
+    (row r holds the positions r mod 4), or 512 equal ones at the
+    positions 0 mod 4, all in row 0: more than any row's W candidate
+    slots here."""
     g = 1e-3 * jax.random.uniform(jax.random.PRNGKey(7), (J,))
     if spread:
-        idx = np.arange(K) * (J // K)
+        idx = np.arange(K) * (J // K) + np.arange(K)
         return g.at[idx].set(5.0 + 0.01 * np.arange(K))
-    return g.at[np.arange(512)].set(5.0)
+    return g.at[4 * np.arange(512)].set(5.0)
 
 
 CASES = {
