@@ -4,8 +4,9 @@ A cell (an entry of ``workloads`` in BENCHMARK.json) names a
 configuration, whose file ``configs/<config>.json`` BENCHMARK.json gives,
 and a traffic mix, read from ``traffic/<traffic>.json``. Its limits on the
 numbers that decide ``correct`` are in ``limits/<cell>.json``. A per-layer
-metric is read by ``metrics/<metric>.py``. Adding any of these takes new
-files and BENCHMARK.json entries, and no edit here.
+metric is read by ``metrics/<metric>.py``. A configuration's model family
+is ``reference/<family>.py``, by the file's ``"reference"``. Adding any
+of these takes new files and BENCHMARK.json entries, and no edit here.
 """
 import json
 import os

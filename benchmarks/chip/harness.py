@@ -126,20 +126,25 @@ class Run:
         self.t += 1
         return metrics
 
-    def window(self, seconds=None, steps=None, annotate=False):
+    def window(self, seconds=None, steps=None, annotate=False,
+               keep_metrics=False):
         """Steps dispatched back to back, the loss fetched every
         ``loss_every`` steps, the end blocking on the last step: until
         ``seconds`` have passed, or for ``steps`` steps. Returns (steps,
-        seconds, fetched losses)."""
+        seconds, fetched losses); with ``keep_metrics`` also each step's
+        metrics dict, as device arrays that no host sync in the window
+        has read."""
         import jax
         every = self.tr["loss_every"]
         ann = (jax.profiler.TraceAnnotation if annotate
                else _NoAnnotation)
-        losses, n = [], 0
+        losses, kept, n = [], [], 0
         t0 = time.perf_counter()
         while True:
             with ann("dispatch"):
                 m = self.advance()
+            if keep_metrics:
+                kept.append(m)
             n += 1
             if n % every == 0:
                 with ann("loss_fetch"):
@@ -149,7 +154,10 @@ class Run:
                 break
         with ann("block"):
             jax.block_until_ready((self.state, m))
-        return n, time.perf_counter() - t0, losses
+        secs = time.perf_counter() - t0
+        if keep_metrics:
+            return n, secs, losses, kept
+        return n, secs, losses
 
     def free_state(self):
         import jax

@@ -75,6 +75,22 @@ def param_specs(cfg):
     ]
 
 
+def forward_flops(cfg, seq):
+    """Model FLOPs per token of the forward: every layer's matrix products
+    (2 FLOPs per multiply-add), the mLSTM and sLSTM recurrences and the
+    output head; not the embedding lookup, norms, activations or the
+    loss. The recurrences are per token, so ``seq`` does not enter."""
+    d, h, di, hd, ds = _dims(cfg)
+    m_proj = 2 * (2 * d * di + 3 * di * di + di * 2 * h + di * d)
+    # per head: C' = f C + i v k^T (4 hd^2), C q (2 hd^2); n' and n . q
+    m_rec = h * (6 * hd * hd + 6 * hd)
+    s_proj = 2 * (4 * d * ds + ds * d)
+    # per unit: c' = f c + i z, n' = f n + i, h = o c / max(n, 1)
+    s_rec = 7 * ds
+    return cfg["n_layers"] // 2 * (m_proj + m_rec + s_proj + s_rec) \
+        + 2 * d * cfg["vocab_size"]
+
+
 def _mlstm(p, x, cfg, mode):
     d, h, di, hd, _ = _dims(cfg)
     S = x.shape[0]

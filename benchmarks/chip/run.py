@@ -79,8 +79,20 @@ def per_layer(cell, reduction):
     return out
 
 
+def counters(kept):
+    """The mean over the steps of each scalar entry of the step's metrics
+    dicts, under the step's own names."""
+    import jax
+    import numpy as np
+    host = jax.device_get(kept)
+    return {k: float(np.mean([m[k] for m in host])) for k in host[0]
+            if np.ndim(host[0][k]) == 0}
+
+
 def traced(run, cell, seed, peaks):
-    """Trace ``trace_steps`` steps; returns the reduction of the trace."""
+    """Trace ``trace_steps`` steps; returns the reduction of the trace,
+    with the mean of each of the step's scalar metrics over the traced
+    steps under ``counters``, read once the trace has ended."""
     import jax
     import trace_reduce
     out = os.path.join(ROOT, ".bench", f"trace-{cell['name']}-{seed}")
@@ -89,7 +101,8 @@ def traced(run, cell, seed, peaks):
     jax.profiler.start_trace(out)
     try:
         with jax.profiler.TraceAnnotation("window"):
-            n, secs, losses = run.window(steps=steps, annotate=True)
+            n, secs, losses, kept = run.window(steps=steps, annotate=True,
+                                               keep_metrics=True)
     finally:
         jax.profiler.stop_trace()
     try:
@@ -99,7 +112,8 @@ def traced(run, cell, seed, peaks):
     finally:
         shutil.rmtree(out, ignore_errors=True)
     import flops
-    red.update(tokens_per_step=run.tokens_per_step, chips=cell["chips"],
+    red.update(counters=counters(kept),
+               tokens_per_step=run.tokens_per_step, chips=cell["chips"],
                flops_per_token=flops.train_per_token(
                    cell["config"], cell["traffic"]["seq"]))
     return n, secs, losses, red

@@ -1,6 +1,6 @@
 """Every configuration, traffic, limit and metric file the benchmark
 names loads, and each configuration matches the arch it names; a new cell
-is found by its files alone."""
+is found by its files alone, and so is a new model family."""
 import importlib
 
 import pytest
@@ -108,3 +108,98 @@ def test_cache_dir_is_set_after_jax_import():
                            env=dict(env, JAX_PLATFORMS="cpu", **extra))
         assert p.returncode == 0, p.stderr
         assert p.stdout.strip() == want
+
+
+FAMILY = '''"""A toy family: an embedding and an output head."""
+from reference.common import einsum, nll_sum
+
+
+def param_specs(cfg):
+    d, v = cfg["d_model"], cfg["vocab_size"]
+    return [(("embed", "head"), (d, v), d ** -0.5),
+            (("embed", "tok"), (v, d), 0.02)]
+
+
+def nll(params, tokens, targets, cfg, mode):
+    x = params["embed"]["tok"][tokens]
+    return nll_sum(x, params["embed"]["head"], targets, mode)
+
+
+def forward_flops(cfg, seq):
+    return 2 * cfg["d_model"] * cfg["vocab_size"]
+'''
+STAGE_METRIC = '''from metrics import stage_ms
+
+
+def read(red):
+    return stage_ms(red, "toy_router")
+'''
+COUNTER_METRIC = '''from metrics import counter
+
+
+def read(red):
+    value = counter(red, "toy_dropped")
+    return None if value is None else 100.0 * value
+'''
+
+
+def test_new_family_is_found_by_its_files(tmp_path, monkeypatch,
+                                          request):
+    """A configuration of a family that exists only under tmp_path, with a
+    stage and a counter that only its metric files name: loaded, counted
+    and read through the harness's own lookups, with no file of the
+    benchmark edited."""
+    import sys
+
+    import jax
+    import numpy as np
+
+    import flops
+    import harness
+    import metrics
+    import reference
+    import run
+    from reference.train import init_params
+
+    fam_dir, metric_dir = tmp_path / "reference", tmp_path / "metrics"
+    fam_dir.mkdir()
+    metric_dir.mkdir()
+    (fam_dir / "toyfam.py").write_text(FAMILY)
+    (metric_dir / "toy_router_ms.py").write_text(STAGE_METRIC)
+    (metric_dir / "toy_dropped_share.py").write_text(COUNTER_METRIC)
+    monkeypatch.setattr(reference, "__path__",
+                        list(reference.__path__) + [str(fam_dir)])
+    monkeypatch.setattr(metrics, "__path__",
+                        list(metrics.__path__) + [str(metric_dir)])
+    request.addfinalizer(lambda: [sys.modules.pop(m, None) for m in (
+        "reference.toyfam", "metrics.toy_router_ms",
+        "metrics.toy_dropped_share")])
+
+    config = {"arch": "toy", "reference": "toyfam", "source": "test",
+              "d_model": 16, "vocab_size": 64}
+    bench, here, name = tiny.make(str(tmp_path / "bench"), config=config)
+    bench["per_layer"] = [
+        {"name": "toy_router_ms", "unit": "ms", "better": "lower",
+         "source": "device_trace", "layer": "toy", "moves": "tokens_per_s"},
+        {"name": "toy_dropped_share", "unit": "%", "better": "lower",
+         "source": "program_counter", "layer": "toy",
+         "moves": "tokens_per_s"}]
+    cell = cells.load_cell(name, bench, here)
+
+    fam = harness.family(cell)
+    assert fam.__file__ == str(fam_dir / "toyfam.py")
+    params = init_params(fam, cell["config"], jax.random.PRNGKey(0))
+    tokens = np.arange(8, dtype=np.int32)
+    assert np.isfinite(float(fam.nll(params, tokens, tokens,
+                                     cell["config"], "f32")))
+    assert flops.train_per_token(cell["config"], 32) == 3 * 2 * 16 * 64
+
+    red = {"steps": 2, "devices": {0: {
+        "layer_ns": {}, "stage_ns": {"toy_router": 4e6, "fwd": 1e6}}},
+        "counters": {"toy_dropped": 0.125, "loss": 4.0}}
+    assert run.per_layer(cell, red) == {
+        "toy_router_ms": {"value": 2.0, "unit": "ms"},
+        "toy_dropped_share": {"value": 12.5, "unit": "%"}}
+    # a reduction with neither stages nor counters reports neither
+    assert run.per_layer(cell, {"steps": 2, "devices": {
+        0: {"layer_ns": {}}}}) == {}

@@ -1,43 +1,63 @@
 """FLOPs per token against XLA's count of a remat-free float32 forward of
-the reference at a reduced size. XLA also counts the norms, activations,
-gates and loss that the model count leaves out, so its count is a little
-higher; the model count must lie within 15 % below it. (At one token XLA
-also folds away a few products with the scans' zero initial state, so
-there 2 % above it is allowed.)"""
+the reference, for every configuration BENCHMARK.json names, at its own
+sizes: each family's ``forward_flops`` is checked with no edit here. XLA
+also counts the norms, activations, gates and loss that the model count
+leaves out, so its count is a little higher; the model count must lie
+within 15 % below it. (At one token XLA also folds away a few products
+with the scans' zero initial state, so there 2 % above it is allowed.)"""
+import importlib
+import json
+import os
+import sys
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+import cells
 import flops
 import tiny
-from reference import xlstm
 from reference.common import Static
 from reference.train import init_params
 
-# widths at which products dominate, as they do at the published sizes
-XLSTM = dict(tiny.XLSTM, d_model=256, vocab_size=2048)
+CONFIGS = cells.load_benchmark()["configs"]
 
 
 def _xla_forward_flops(fam, cfg, seq):
-    params = init_params(fam, cfg, jax.random.PRNGKey(0))
-    tokens = jnp.zeros((seq,), jnp.int32)
+    """XLA's count, compiled from the parameters' shapes alone."""
+    params = jax.eval_shape(lambda k: init_params(fam, cfg, k),
+                            jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((seq,), jnp.int32)
     f = jax.jit(lambda p, t: fam.nll(p, t, t, Static(cfg), "f32"))
     cost = f.lower(params, tokens).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     return cost["flops"]
 
 
-def test_forward_flops_match_xla():
+@pytest.mark.parametrize("conf", CONFIGS, ids=lambda c: c["name"])
+def test_forward_flops_match_xla(conf):
     # one token: XLA counts a scan's body once, so the recurrence is
     # counted right only where the scan runs once
-    xla = _xla_forward_flops(xlstm, XLSTM, 1)
-    ours = flops.forward_per_token(XLSTM, 1)
+    with open(os.path.join(cells.ROOT, conf["file"])) as f:
+        cfg = json.load(f)
+    fam = importlib.import_module("reference." + cfg["reference"])
+    xla = _xla_forward_flops(fam, cfg, 1)
+    ours = flops.forward_per_token(cfg, 1)
     assert 0.85 * xla <= ours <= 1.02 * xla, (ours, xla)
 
 
 def test_train_is_three_forwards():
-    assert flops.train_per_token(XLSTM, 64) == pytest.approx(
-        3 * flops.forward_per_token(XLSTM, 64))
+    assert flops.train_per_token(tiny.XLSTM, 64) == pytest.approx(
+        3 * flops.forward_per_token(tiny.XLSTM, 64))
+
+
+def test_family_without_a_count_is_an_error(monkeypatch):
+    """No family falls back on another's count."""
+    monkeypatch.setitem(sys.modules, "reference.nocount",
+                        types.ModuleType("reference.nocount"))
+    with pytest.raises(AttributeError, match=r"reference\.nocount"):
+        flops.train_per_token(dict(tiny.XLSTM, reference="nocount"), 64)
 
 
 def test_published_sizes():

@@ -6,8 +6,12 @@ named scope the harness put around the call into its layer, where there
 is one; otherwise its metadata's stack of source frames decides, by the
 innermost frame under ``src/repro/`` (``layers.json``). Many ops of the
 step lose their frames in lowering, so the scopes carry most of the
-attribution. Each op counts its self time: its duration less
-that of the ops nested inside it on the same line. Busy time is the union
+attribution. Each op of the step also has the program's stage that its
+op_name names (``repro.core.stages.stage_of``); an op that names none
+but whose ``calls=`` computation holds staged instructions, such as a
+fusion, is counted apart under the most common of their stages. Each op
+counts its self time: its duration less that of the ops nested inside
+it on the same line. Busy time is the union
 of op intervals inside the traced window, which the host span named
 ``window`` bounds; idle is the rest. A collective's exposed time is the
 part of it during which no other op runs on that device.
@@ -19,11 +23,15 @@ import json
 import os
 import re
 
+from repro.core.stages import stage_of
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
                "collective-permute", "all-to-all")
 HOST_SPANS = ("dispatch", "loss_fetch", "block")
 
+_COMP = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([a-z][\w\-]*)\(")
 _META_FRAME = re.compile(r"stack_frame_id=(\d+)")
 _META_NAME = re.compile(r'op_name="([^"]*)"')
@@ -47,12 +55,15 @@ def _repro_path(path):
 
 def hlo_sources(hlo_text, layers=None):
     """{instruction name: (opcode, layer, innermost repro file or None,
-    line)}."""
+    line, stage, inner stage)}. The stage is the one its own op_name
+    names, or None; the inner stage, for an instruction with none, is the
+    most common stage of the instructions of the computation it
+    ``calls=``, or None."""
     layers = layers or load_layers()
     scope = re.compile(re.escape(layers["scope_prefix"]) + r"([A-Za-z]+)")
     files, locs, frames = {}, {}, {}
-    section = None
-    ops = {}
+    section = comp = None
+    ops, comp_stages = {}, collections.defaultdict(collections.Counter)
     for line in hlo_text.splitlines():
         s = line.strip()
         if s in ("FileNames", "FunctionNames", "FileLocations",
@@ -79,9 +90,16 @@ def hlo_sources(hlo_text, layers=None):
         elif section is None:
             m = _INSTR.match(line)
             if m:
-                ops[m.group(1)] = (m.group(2), line)
+                mn = _META_NAME.search(line)
+                stage = stage_of(mn.group(1)) if mn else None
+                ops[m.group(1)] = (m.group(2), line, stage)
+                if stage and comp:
+                    comp_stages[comp][stage] += 1
+            elif not line[:1].isspace():
+                mc = _COMP.match(line)
+                comp = mc.group(1) if mc else None
     out = {}
-    for name, (opcode, line) in ops.items():
+    for name, (opcode, line, stage) in ops.items():
         src = (None, 0)
         fm = _META_FRAME.search(line)
         if fm:
@@ -102,7 +120,12 @@ def hlo_sources(hlo_text, layers=None):
         mn = _META_NAME.search(line)
         scoped = scope.findall(mn.group(1)) if mn else []
         layer = scoped[-1] if scoped else layer_of(src[0], layers)
-        out[name] = (opcode, layer, src[0], src[1])
+        inner = None
+        if stage is None:
+            held = sum((comp_stages[c] for c in _CALLS.findall(line)),
+                       collections.Counter())
+            inner = held.most_common(1)[0][0] if held else None
+        out[name] = (opcode, layer, src[0], src[1], stage, inner)
     return out
 
 
@@ -191,7 +214,11 @@ def reduce_events(trace, sources, *, steps, j_local, peaks, step_module,
     "modules": [[start_ns, dur_ns, name]]}}, "host": [[name, start_ns,
     dur_ns]]}, as read_xplane gives it; sources: hlo_sources() of the
     step, whose module is ``step_module``. Returns the reduction that the
-    metric readers take."""
+    metric readers take: per device, self ns by layer (``layer_ns``) and,
+    over the step module's ops alone, by stage (``stage_ns``, with the ops
+    that name no stage, in themselves or inside, under ``unstaged``) and,
+    for ops whose stage is only inside the computation they call, by that
+    stage (``stage_inner_ns``, in no stage's sum)."""
     layers = layers or load_layers()
     host = trace["host"]
     win = [h for h in host if h[0] == "window"]
@@ -210,11 +237,16 @@ def reduce_events(trace, sources, *, steps, j_local, peaks, step_module,
               for (s, d, n), m in zip(evs, mods) if s < w1 and s + d > w0]
         self_ns = _self_times(iv)
         layer_ns = collections.Counter()
+        stage_ns, inner_ns = collections.Counter(), collections.Counter()
         coll, other = [], []
         for (s, e, op, module), ns in zip(iv, self_ns):
             if module == step_module:
-                opcode, layer, path, lineno = sources.get(
-                    op, ("", "unattributed", None, 0))
+                opcode, layer, path, lineno, stage, inner = sources.get(
+                    op, ("", "unattributed", None, 0, None, None))
+                if stage or not inner:
+                    stage_ns[stage or "unstaged"] += ns
+                else:
+                    inner_ns[inner] += ns
             else:
                 opcode, layer, path, lineno = "", "other:" + module, None, 0
             if is_collective(opcode):
@@ -231,7 +263,9 @@ def reduce_events(trace, sources, *, steps, j_local, peaks, step_module,
         busy_ns = sum(e - s for s, e in busy)
         cu = _union(coll)
         exposed = sum(e - s for s, e in cu) - _overlap(cu, _union(other))
-        per_dev[dev] = {"layer_ns": dict(layer_ns), "busy_ns": busy_ns,
+        per_dev[dev] = {"layer_ns": dict(layer_ns),
+                        "stage_ns": dict(stage_ns),
+                        "stage_inner_ns": dict(inner_ns), "busy_ns": busy_ns,
                         "window_ns": w1 - w0, "sync_exposed_ns": exposed}
         if dev == first:
             edges = [w0] + [x for b in busy for x in b] + [w1]
